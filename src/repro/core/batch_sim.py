@@ -494,11 +494,8 @@ def _accel_default() -> bool:
     """True when SD counting should run in the Pallas kernel (TPU host)."""
     global _ACCEL
     if _ACCEL is None:
-        try:
-            import jax
-            _ACCEL = jax.default_backend() == "tpu"
-        except Exception:  # pragma: no cover - jax always present in-tree
-            _ACCEL = False
+        import jax
+        _ACCEL = jax.default_backend() == "tpu"
     return _ACCEL
 
 

@@ -32,7 +32,7 @@ arrays.  This module keeps the whole window decision on device:
     Zero host syncs inside the window: the single sync is the final
     result fetch (asserted by ``StageProfile``).
   * **Bit parity.**  Off TPU the program runs in float64/int64
-    (``jax.experimental.enable_x64`` scoped to this pipeline only), and
+    (``jax.enable_x64`` scoped to this pipeline only), and
     every per-tenant output — curve edges *and* heights, URD sizes, write
     ratios, allocations — is bit-identical to the host path; tier-1
     therefore exercises the full pipeline everywhere.  On TPU the program
@@ -125,8 +125,7 @@ def _f64_default() -> bool:
 
 def _x64(f64: bool):
     if f64:
-        from jax.experimental import enable_x64
-        return enable_x64()
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
 
 

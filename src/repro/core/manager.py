@@ -295,6 +295,10 @@ class ECICacheManager:
         if pipeline not in ("host", "device", "sharded"):
             raise ValueError(f"pipeline must be 'host', 'device' or "
                              f"'sharded', got {pipeline!r}")
+        if pipeline != "host" and percentile < 100.0:
+            raise ValueError(f"pipeline={pipeline!r} computes URD sizes "
+                             "from the curve store (percentile=100); use "
+                             "pipeline='host' for percentile < 100")
         self.capacity = int(capacity)
         self.capacity2 = int(capacity2)
         self.c_min = int(c_min)
@@ -316,8 +320,7 @@ class ECICacheManager:
         self.engine = engine
         # "device" routes each analyze through the fused device window
         # program (core.device_pipeline), "sharded" through its mesh twin
-        # (core.shard_pipeline); both fall back to the host pipeline when
-        # percentile < 100 (the device programs are percentile-free)
+        # (core.shard_pipeline); both need percentile == 100
         self.pipeline = pipeline
         init = int(initial_blocks if initial_blocks is not None else c_min)
         self.tenants = [TenantState(n, LRUCache(init)) for n in tenant_names]
@@ -494,7 +497,7 @@ class ECICacheManager:
         only when every rung failed (total outage → last-known-good).
         Without fault tolerance this is exactly the single fused call."""
         rate = self.effective_sample_rate()
-        pipe = (self.pipeline if self.percentile >= 100.0 else "host")
+        pipe = self.pipeline
         traces = [self.tenants[i].window_trace() for i in act]
         # the device program recounts on device, so precomputed TRD arrays
         # are only forwarded to the host pipeline; a deferred (straggler)

@@ -22,7 +22,7 @@ array code, in one of three pipelines:
     scatter), Alg.-3 write ratios (device bincount) and the URD sizes all
     run inside a single jit — **zero host syncs inside the window**, one
     sync to fetch the results.  Off TPU the program runs under
-    ``jax.experimental.enable_x64`` and every output is bit-identical to
+    ``jax.enable_x64`` and every output is bit-identical to
     the host pipeline (differential-tested in ``tests/test_monitor_scale``
     across both routes); on TPU it runs in f32/int32 with a documented
     tolerance.  ``precomputed_trd`` is ignored on this path — the program

@@ -372,10 +372,15 @@ def pgd_solve(hs: list[HitRatioFunction], capacity: int,
     # Snap each tenant down to its nearest breakpoint (never exceeds c*),
     # then spend any leftover with single marginal-density repair steps —
     # still a local method, faithful to the first-order character of fmincon.
+    # The snap never goes below the c_min floor when the floors fit: c*
+    # already respects them, so the sum stays within the budget.
     sizes = np.zeros(n, dtype=np.int64)
     for i, h in enumerate(hs):
         k = np.searchsorted(h.edges, c_star[i], side="right") - 1
         sizes[i] = int(h.edges[max(k, 0)])
+    floors = lo.astype(np.int64)
+    if int(floors.sum()) <= capacity:
+        sizes = np.maximum(sizes, floors)
     leftover = capacity - int(sizes.sum())
     gain = t_slow - t_fast
     while leftover > 0:

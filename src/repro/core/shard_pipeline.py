@@ -61,7 +61,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.batch_sim import padded_segment_layout, padded_tape_links
@@ -333,9 +332,9 @@ def _shard_programs(key: tuple) -> dict:
                 lax.psum(kcnt, _AXIS), lax.psum(urd, _AXIS),
                 lax.psum(wcnt, _AXIS))
 
-    smap = shard_map(shard_body, mesh=mesh,
-                     in_specs=(tape_specs, rep_specs),
-                     out_specs=(P(),) * 5, check_rep=False)
+    smap = jax.shard_map(shard_body, mesh=mesh,
+                         in_specs=(tape_specs, rep_specs),
+                         out_specs=(P(),) * 5, check_vma=False)
 
     def monitor_core(tape, rep):
         edges_c, hgt_c, kcnt, urd, wcnt = smap(tape, rep)

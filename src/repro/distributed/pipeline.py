@@ -21,7 +21,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["pipeline_forward", "stack_stages"]
@@ -90,6 +89,6 @@ def pipeline_forward(stage_fn, stage_params, x, *, mesh, axis: str = "pod",
 
     in_specs = (P(axis), P(*[None] * x.ndim))
     out_specs = P(*[None] * x.ndim)
-    fn = shard_map(per_pod, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(per_pod, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(stage_params, x)

@@ -200,14 +200,15 @@ def control_plane_mesh(n_shards: int | None = None):
 
     The ECI control-plane shard pipeline (``core.shard_pipeline``)
     partitions the window tape by whole tenant-segments over this axis.
-    Uses every local device by default; ``n_shards`` caps the mesh (and
-    degrades gracefully to however many devices exist, so single-device
-    hosts run the sharded path as a 1-shard mesh bit-identically).  On
-    CPU hosts the test/CI harness forces
+    Uses every local device by default; ``n_shards`` asks for exactly
+    that many shards and raises ``ValueError`` when the host has fewer
+    devices.  On CPU hosts the test/CI harness forces
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the mesh
     exercises real multi-device semantics everywhere.
     """
     devices = jax.devices()
-    k = len(devices) if n_shards is None else max(1, min(int(n_shards),
-                                                         len(devices)))
+    k = len(devices) if n_shards is None else int(n_shards)
+    if not 1 <= k <= len(devices):
+        raise ValueError(f"control_plane_mesh: asked for {k} shards, "
+                         f"{len(devices)} devices exist")
     return jax.sharding.Mesh(np.array(devices[:k]), ("shards",))

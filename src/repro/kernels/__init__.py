@@ -1,20 +1,6 @@
 """Pallas TPU kernels for the performance-critical hot spots.
 
 Each subpackage ships kernel.py (pl.pallas_call + BlockSpec VMEM tiling),
-ops.py (jit'd wrapper with interpret fallback on CPU) and ref.py (pure-jnp
-oracle used by the allclose test sweeps).
+ops.py (jit'd wrapper: the kernel on TPU, the jnp oracle elsewhere) and
+ref.py (pure-jnp oracle used by the allclose test sweeps).
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-__all__ = ["tpu_compiler_params"]
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable ``compiler_params`` for ``pl.pallas_call``.
-
-    jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``;
-    support both so the kernels run on every toolchain in the fleet.
-    """
-    cls = getattr(_pltpu, "CompilerParams", None) \
-        or getattr(_pltpu, "TPUCompilerParams")
-    return cls(**kwargs)

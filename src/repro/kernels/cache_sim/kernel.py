@@ -10,9 +10,16 @@ the derivation).  This is the ``urd_scan`` counting formulation with one
 extra per-``j`` occupancy mask: ``occ = 1`` everywhere for WB/WT (every
 access installs or touches), ``occ = is_read`` for RO write-around.
 
-Same layout as ``urd_scan``: O(n²/tile) masked counts over the (i, j)
+Same counting as ``urd_scan``: O(n²/tile) masked counts over the (i, j)
 plane, grid (num_i_tiles, num_j_tiles) with j innermost and an fp32 VMEM
 accumulator revisited across j-tiles — pure VPU work.
+
+Every per-access vector is laid out as ``[num_tiles, 1, tile]`` and walked
+one ``(1, tile)`` row tile per grid step (``_row_spec``): the squeezed
+leading axis keeps the block's last two dims equal to ``(1, tile)``, which
+the TPU compiler accepts for ``tile`` a multiple of 128 (a ``(1, tile)``
+block of a 2-D ``[num_tiles, tile]`` array is refused — its second-minor
+dim is neither a multiple of 8 nor the full extent).
 """
 from __future__ import annotations
 
@@ -23,10 +30,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 __all__ = ["cache_sim_scan", "cache_sim_segments_scan",
            "cache_sim_levels_scan", "live_count_scan"]
+
+
+def _row_spec(tile: int, index_map) -> pl.BlockSpec:
+    """One ``(1, tile)`` row tile of a ``[num_tiles, 1, tile]`` vector."""
+    return pl.BlockSpec((None, 1, tile), index_map)
+
+
+def _row_tiles(x: jax.Array, nt: int, tile: int) -> jax.Array:
+    return x.reshape(nt, 1, tile).astype(jnp.int32)
+
+
+def _i_map(i, j):
+    return (i, 0, 0)
+
+
+def _j_map(i, j):
+    return (j, 0, 0)
 
 
 def _kernel(prev_ref, nxt_ref, occ_ref, out_ref, acc_scr, *, tile: int):
@@ -76,26 +99,20 @@ def cache_sim_scan(prev: jax.Array, nxt: jax.Array, occ: jax.Array, *,
         # padded j cols: never occupy, and nxt = -1 as belt-and-braces
         nxt = jnp.pad(nxt, (0, pad), constant_values=-1)
         occ = jnp.pad(occ, (0, pad), constant_values=0)
-    prev2 = prev.reshape(nt, tile).astype(jnp.int32)
-    nxt2 = nxt.reshape(nt, tile).astype(jnp.int32)
-    occ2 = occ.reshape(nt, tile).astype(jnp.int32)
-
     kernel = functools.partial(_kernel, tile=tile)
     out = pl.pallas_call(
         kernel,
         grid=(nt, nt),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, tile), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nt, tile), jnp.int32),
+        in_specs=[_row_spec(tile, _i_map), _row_spec(tile, _j_map),
+                  _row_spec(tile, _j_map)],
+        out_specs=_row_spec(tile, _i_map),
+        out_shape=jax.ShapeDtypeStruct((nt, 1, tile), jnp.int32),
         scratch_shapes=[pltpu.VMEM((tile, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(prev2, nxt2, occ2)
+        name="cache_sim_scan",
+    )(*(_row_tiles(a, nt, tile) for a in (prev, nxt, occ)))
     return out.reshape(nt * tile)[:n]
 
 
@@ -110,8 +127,10 @@ def _segments_kernel(prev_ref, nxt_ref, occ_ref, out_ref, acc_scr, *,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     prev_i = prev_ref[0]                                 # [1, tile] int32
-    # the j plane is restricted to the i-tile's seg_width-aligned block
-    j_base = (ii * tile) // seg_width * seg_width + jj * tile
+    # the j plane is restricted to the i-tile's aligned block (the
+    # segment's own block, or the i tile itself for segments below a tile)
+    blk = max(seg_width, tile)
+    j_base = (ii * tile) // blk * blk + jj * tile
     i_idx = ii * tile + jax.lax.broadcasted_iota(
         jnp.int32, (tile, tile), 0)                      # rows: i
     j_idx = j_base + jax.lax.broadcasted_iota(
@@ -125,6 +144,9 @@ def _segments_kernel(prev_ref, nxt_ref, occ_ref, out_ref, acc_scr, *,
         & (nxt_j.reshape(1, tile) >= i_idx)
         & (occ_j.reshape(1, tile) > 0)
     )
+    if seg_width < tile:
+        # several segments share the tile: keep j inside i's own segment
+        contrib = contrib & (j_idx >= i_idx // seg_width * seg_width)
     acc_scr[...] += jnp.sum(contrib.astype(jnp.float32), axis=1,
                             keepdims=True)
 
@@ -147,6 +169,9 @@ def cache_sim_segments_scan(prev: jax.Array, nxt: jax.Array, occ: jax.Array,
     The grid therefore shrinks from ``nt x nt`` to
     ``nt x (seg_width / tile)`` — the j loop visits only the aligned
     block, the kernel body is ``_kernel`` with the absolute j base offset.
+    Segments narrower than a tile share it: the grid is ``nt x 1`` and the
+    kernel masks ``j`` to the query's own ``seg_width`` block, so ``tile``
+    stays a TPU-legal lane width whatever the segment width.
     Cold and padding rows return prefix counts — callers mask them.
 
     This is the TPU counting route of both the per-width host launches
@@ -159,32 +184,33 @@ def cache_sim_segments_scan(prev: jax.Array, nxt: jax.Array, occ: jax.Array,
     oracle instead of this kernel's interpret mode.
     """
     n = prev.shape[0]
-    if seg_width < tile:
-        tile = int(seg_width)                # pow2 >= 16: still a valid tile
-    nt = n // tile
-    jt = seg_width // tile
-    prev2 = prev.reshape(nt, tile).astype(jnp.int32)
-    nxt2 = nxt.reshape(nt, tile).astype(jnp.int32)
-    occ2 = occ.reshape(nt, tile).astype(jnp.int32)
+    nt = -(-n // tile)
+    pad = nt * tile - n
+    if pad:                                  # only when seg_width < tile
+        prev = jnp.pad(prev, (0, pad), constant_values=n)
+        nxt = jnp.pad(nxt, (0, pad), constant_values=-1)
+        occ = jnp.pad(occ, (0, pad), constant_values=0)
+    blk = max(int(seg_width), tile)
+    jt = blk // tile
+
+    def j_map(i, j):
+        return ((i * tile) // blk * jt + j, 0, 0)
 
     kernel = functools.partial(_segments_kernel, tile=tile,
-                               seg_width=seg_width)
-    j_map = lambda i, j: ((i * tile) // seg_width * jt + j, 0)  # noqa: E731
+                               seg_width=int(seg_width))
     out = pl.pallas_call(
         kernel,
         grid=(nt, jt),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, tile), j_map),
-            pl.BlockSpec((1, tile), j_map),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nt, tile), jnp.int32),
+        in_specs=[_row_spec(tile, _i_map), _row_spec(tile, j_map),
+                  _row_spec(tile, j_map)],
+        out_specs=_row_spec(tile, _i_map),
+        out_shape=jax.ShapeDtypeStruct((nt, 1, tile), jnp.int32),
         scratch_shapes=[pltpu.VMEM((tile, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(prev2, nxt2, occ2)
+        name="cache_sim_segments_scan",
+    )(*(_row_tiles(a, nt, tile) for a in (prev, nxt, occ)))
     return out.reshape(nt * tile)[:n]
 
 
@@ -235,24 +261,19 @@ def live_count_scan(nxt: jax.Array, occ: jax.Array, *, tile: int = 256,
         # padded j cols: never occupy, and nxt = -1 never covers a row
         nxt = jnp.pad(nxt, (0, pad), constant_values=-1)
         occ = jnp.pad(occ, (0, pad), constant_values=0)
-    nxt2 = nxt.reshape(nt, tile).astype(jnp.int32)
-    occ2 = occ.reshape(nt, tile).astype(jnp.int32)
-
     kernel = functools.partial(_live_kernel, tile=tile)
     out = pl.pallas_call(
         kernel,
         grid=(nt, nt),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, tile), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nt, tile), jnp.int32),
+        in_specs=[_row_spec(tile, _j_map), _row_spec(tile, _j_map)],
+        out_specs=_row_spec(tile, _i_map),
+        out_shape=jax.ShapeDtypeStruct((nt, 1, tile), jnp.int32),
         scratch_shapes=[pltpu.VMEM((tile, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(nxt2, occ2)
+        name="live_count_scan",
+    )(_row_tiles(nxt, nt, tile), _row_tiles(occ, nt, tile))
     return out.reshape(nt * tile)[:n]
 
 
@@ -313,23 +334,23 @@ def cache_sim_levels_scan(prev: jax.Array, nxt: jax.Array, occ: jax.Array,
         occ = jnp.pad(occ, (0, pad), constant_values=0)
         cap1 = jnp.pad(cap1, (0, pad), constant_values=0)
         captot = jnp.pad(captot, (0, pad), constant_values=0)
-    shape2 = (nt, tile)
-    args = [a.reshape(shape2).astype(jnp.int32)
-            for a in (prev, nxt, occ, cap1, captot)]
+    shape3 = (nt, 1, tile)
+    args = [_row_tiles(a, nt, tile) for a in (prev, nxt, occ, cap1, captot)]
 
     kernel = functools.partial(_levels_kernel, tile=tile)
-    i_spec = pl.BlockSpec((1, tile), lambda i, j: (i, 0))
-    j_spec = pl.BlockSpec((1, tile), lambda i, j: (j, 0))
+    i_spec = _row_spec(tile, _i_map)
+    j_spec = _row_spec(tile, _j_map)
     l1, un = pl.pallas_call(
         kernel,
         grid=(nt, nt),
         in_specs=[i_spec, j_spec, j_spec, i_spec, i_spec],
         out_specs=(i_spec, i_spec),
-        out_shape=(jax.ShapeDtypeStruct(shape2, jnp.int32),
-                   jax.ShapeDtypeStruct(shape2, jnp.int32)),
+        out_shape=(jax.ShapeDtypeStruct(shape3, jnp.int32),
+                   jax.ShapeDtypeStruct(shape3, jnp.int32)),
         scratch_shapes=[pltpu.VMEM((tile, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="cache_sim_levels_scan",
     )(*args)
     return (l1.reshape(nt * tile)[:n], un.reshape(nt * tile)[:n])

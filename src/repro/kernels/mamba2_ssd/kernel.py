@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 __all__ = ["mamba2_ssd"]
 
@@ -93,7 +92,7 @@ def mamba2_ssd(x_dt: jax.Array, B: jax.Array, C: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, P), lambda b, ci: (b, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x_dt.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x_dt, B, C, seg)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 __all__ = ["urd_scan"]
 
@@ -89,7 +88,7 @@ def urd_scan(prev: jax.Array, nxt: jax.Array, *, tile: int = 256,
         out_specs=pl.BlockSpec((1, tile), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nt, tile), jnp.int32),
         scratch_shapes=[pltpu.VMEM((tile, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(prev2, nxt2)

@@ -1,10 +1,12 @@
 """Serving launcher: multi-tenant engine + ECI-managed pool.
 
-On real hardware this drives the pjit-compiled paged decode across the pod;
-here ``--local`` runs the reduced config end-to-end on CPU.
+Serves ``--arch`` at its published widths with seeded weights; ``--smoke``
+serves the architecture's reduced ``SMOKE_CONFIG`` instead (CPU-sized).
+The KV page pool is held in the model's dtype.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3_0_6b --local \
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3_0_6b \
         --requests 12
+    PYTHONPATH=src python -m repro.launch.serve --smoke    # reduced config
 """
 from __future__ import annotations
 
@@ -16,8 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.cache import BlockPool, TieredKVCache
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.core import ECICacheManager
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import model as M
 from repro.models.attention import build_heads
 from repro.serve.engine import MultiTenantEngine, Request
@@ -26,7 +29,8 @@ from repro.serve.engine import MultiTenantEngine, Request
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_0_6b")
-    ap.add_argument("--local", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced SMOKE_CONFIG")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--tenants", type=int, default=2)
     ap.add_argument("--pool-pages", type=int, default=512)
@@ -35,11 +39,12 @@ def main() -> None:
     ap.add_argument("--max-new-tokens", type=int, default=8)
     args = ap.parse_args()
 
-    cfg = get_smoke_config(args.arch)
+    setup_compile_cache()
+    cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     hq, hkv = build_heads(cfg, 1)
     pool = BlockPool(args.pool_pages, args.page_size, cfg.n_layers, hkv,
-                     cfg.head_dim, dtype=jnp.float32)
+                     cfg.head_dim, dtype=jnp.dtype(cfg.dtype))
     manager = ECICacheManager(
         args.capacity, [f"tenant{i}" for i in range(args.tenants)],
         c_min=8, initial_blocks=args.capacity // max(args.tenants, 1))

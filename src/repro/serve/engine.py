@@ -56,8 +56,10 @@ def _prefix_key(tenant: int, tokens: np.ndarray) -> tuple:
 
 @partial(jax.jit, static_argnames=("cfg", "tp"))
 def prefill_with_kv(params: Param, cfg: ModelConfig, tokens: jax.Array,
-                    tp: int = 1):
-    """Forward returning (last_logits, k [L,B,S,Hkv,D], v [L,B,S,Hkv,D])."""
+                    tp: int = 1, last: jax.Array | int = -1):
+    """Forward returning (logits at position ``last``, k [L,B,S,Hkv,D],
+    v [L,B,S,Hkv,D]).  ``last`` is the prompt's final real token when the
+    tokens are padded to whole pages."""
     from repro.models.model import _attn_mlp_block, _lm_head  # noqa
     hq, hkv = build_heads(cfg, tp)
     B, S = tokens.shape
@@ -81,7 +83,7 @@ def prefill_with_kv(params: Param, cfg: ModelConfig, tokens: jax.Array,
 
     h, (ks, vs) = jax.lax.scan(body, h, params["layers"])
     h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    logits = jnp.einsum("bd,dv->bv", h[:, -1], _lm_head(params, cfg),
+    logits = jnp.einsum("bd,dv->bv", h[:, last], _lm_head(params, cfg),
                         preferred_element_type=_F32)
     return logits, ks, vs
 
@@ -119,7 +121,8 @@ class MultiTenantEngine:
         n_pages = -(-S // page)
         pad = n_pages * page - S
         tok = jnp.asarray(np.pad(prompt, (0, pad))[None, :])
-        logits, ks, vs = prefill_with_kv(self.params, cfg, tok, self.tp)
+        logits, ks, vs = prefill_with_kv(self.params, cfg, tok, self.tp,
+                                         S - 1)
 
         for pi in range(n_pages):
             key = _prefix_key(req.tenant, prompt[:(pi + 1) * page])
@@ -141,9 +144,12 @@ class MultiTenantEngine:
                 if pid is not None and self.pool.k_pages is not None:
                     sl = slice(pi * page, (pi + 1) * page)
                     self.pool.write_page(ks[:, 0, sl], vs[:, 0, sl], pid)
-            if pid is not None:
-                self.pool.pin(pid)
-            req.pages.append(pid if pid is not None else 0)
+            if pid is None:
+                raise RuntimeError(
+                    f"KV pool exhausted: no page for request {req.rid} "
+                    f"(prompt page {pi}); every page is pinned")
+            self.pool.pin(pid)
+            req.pages.append(pid)
         req.length = S
         req.generated.append(int(jnp.argmax(logits[0, :cfg.vocab_size])))
         self.active.append(req)
@@ -163,9 +169,12 @@ class MultiTenantEngine:
                 pid = self.pool.by_key.get(key)
                 if pid is None:
                     pid, _ = self.pool.allocate(-2, None, quota=None)
-                if pid is not None:
-                    self.pool.pin(pid)
-                r.pages.append(pid if pid is not None else 0)
+                if pid is None:
+                    raise RuntimeError(
+                        f"KV pool exhausted: no decode page for request "
+                        f"{r.rid}; every page is pinned")
+                self.pool.pin(pid)
+                r.pages.append(pid)
 
         tables = np.zeros((B, self.max_pages), np.int32)
         lens = np.zeros((B,), np.int32)

@@ -152,7 +152,6 @@ def test_compressed_psum_shard_map():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.launch.mesh import make_test_mesh
         from repro.optim.compression import compressed_psum, init_error_state
         mesh = make_test_mesh(4, 1)
@@ -163,9 +162,9 @@ def test_compressed_psum_shard_map():
         def body(g, e):
             return compressed_psum(g, e, "data")
 
-        f = shard_map(body, mesh=mesh,
-                      in_specs=(P("data"), P("data")),
-                      out_specs=(P("data"), P("data")))
+        f = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P("data"), P("data")),
+                          out_specs=(P("data"), P("data")))
         red, new_err = f(g, err)
         # DP mean across shards, each with its own int8 scale
         expect = jnp.broadcast_to(rows.mean(axis=0, keepdims=True), rows.shape)

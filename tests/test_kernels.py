@@ -133,7 +133,8 @@ def test_cache_sim_scan_sweep(n, tile, occ_mode):
     assert jnp.array_equal(out, ref)
 
 
-@pytest.mark.parametrize("w,tile", [(32, 16), (64, 32)])
+@pytest.mark.parametrize("w,tile", [(32, 16), (64, 32),
+                                    (16, 64), (16, 128)])  # w < tile
 @slow_sweep
 def test_cache_sim_segments_scan_sweep(w, tile):
     """Segment-restricted kernel vs the dense segments oracle (interpret).

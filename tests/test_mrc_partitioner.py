@@ -127,6 +127,21 @@ def test_pgd_respects_constraints_and_is_competitive():
     assert res.latency <= greedy.latency * 1.25 + 1e-9
 
 
+def test_pgd_snap_keeps_c_min_floors():
+    """Snapping c* down to a breakpoint must not cut a tenant below its
+    ``min(c_min, urd)`` floor: a tenant whose breakpoints straddle c_min
+    (here 38 and 120 around a floor of 50) stays at the floor."""
+    hs = [_mk_h([0, 38, 120], [0.0, 0.05, 0.06]),
+          _mk_h([0, 100, 400], [0.0, 0.6, 0.9]),
+          _mk_h([0, 80, 300], [0.0, 0.5, 0.8])]
+    cap = 450
+    res = pgd_solve(hs, cap, 1.0, 20.0, c_min=50)
+    assert not res.feasible
+    assert int(res.sizes.sum()) <= cap
+    assert all(s >= min(50, h.max_useful_size)
+               for h, s in zip(hs, res.sizes))
+
+
 def test_appendix_d_convexity_of_relaxation():
     """App. D: the relaxed objective is convex along any segment when the
     interpolated h is concave (checked numerically)."""

@@ -134,15 +134,16 @@ def test_rl001_sees_through_shard_map_bodies(tmp_path):
     a jitted function (the rule gap the shard pipeline exposed): flagged,
     while a sync-free body stays clean."""
     src = """
+        import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def body(tape):
             s = jnp.sum(tape["x"])
             return s.item()
 
-        prog = shard_map(body, mesh=None, in_specs=(P(),), out_specs=P())
+        prog = jax.shard_map(body, mesh=None, in_specs=(P(),),
+                             out_specs=P())
     """
     res = run_lint(tmp_path, {"core/shard_pipeline.py": src},
                    select=["RL001"])
