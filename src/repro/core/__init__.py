@@ -11,8 +11,8 @@ from repro.core.batch_sim import (reuse_distances_fast,
 from repro.core.characterize import (PhaseDetector, PhaseEvent,
                                      WindowFeatures, characterize_trace,
                                      characterize_windows)
-from repro.core.device_pipeline import (DeviceWindowPipeline, StageProfile,
-                                        WindowDecision, greedy_walk_device,
+from repro.core.device_pipeline import (DeviceWindowPipeline, WindowDecision,
+                                        greedy_walk_device,
                                         monitor_window_device,
                                         transfer_sanitizer)
 from repro.core.faults import (FAULT_KINDS, FaultPlan, FaultSpec,
@@ -34,6 +34,7 @@ from repro.core.reuse_distance import (RDResult, auto_sample_rate, max_rd,
                                        urd_cache_blocks)
 from repro.core.simulator import (LRUCache, SimResult, rebalance_levels,
                                   simulate)
+from repro.core.stage_profile import StageProfile
 from repro.core.trace import (AccessClass, Trace, TraceError,
                               classify_accesses, request_type_mix,
                               total_cache_writes_wb, validate_trace,

@@ -140,6 +140,7 @@ import numpy as np
 from repro.core.reuse_distance import RDResult
 from repro.core.simulator import (LRUCache, SimResult, rebalance_levels,
                                   simulate)
+from repro.core.stage_profile import StageProfile, _pstep
 from repro.core.trace import Trace, prev_next_occurrence
 from repro.core.write_policy import WritePolicy
 
@@ -673,7 +674,7 @@ def _ro_device_core():
     import jax.numpy as jnp
 
     @jax.jit
-    def run(rd, pv, nxt, force, cap):
+    def ro_token_replay(rd, pv, nxt, force, cap):
         n = rd.shape[0]
 
         def body(t, carry):
@@ -717,7 +718,7 @@ def _ro_device_core():
         death, dirty, fl, _, _ = jax.lax.fori_loop(0, n, body, carry)
         return death, dirty, fl
 
-    return run
+    return ro_token_replay
 
 
 def ro_token_replay_device(is_read_blk: np.ndarray, prev_blk: np.ndarray,
@@ -760,7 +761,8 @@ def _ro_levels_device_core():
     import jax.numpy as jnp
 
     @jax.jit
-    def run(rd, pv, nxt, force, cap1, cap2, l2_end, clean2):
+    def ro_token_replay_levels(rd, pv, nxt, force, cap1, cap2, l2_end,
+                               clean2):
         n = rd.shape[0]
 
         def body(t, carry):
@@ -836,7 +838,7 @@ def _ro_levels_device_core():
             0, n, body, carry)
         return death, dirty, lvl, fl, dem
 
-    return run
+    return ro_token_replay_levels
 
 
 def ro_token_replay_levels_device(is_read_blk: np.ndarray,
@@ -892,7 +894,8 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
                   capacities2=None, policies2=None,
                   caches2: list[LRUCache | None] | None = None,
                   t_fast2: float | None = None,
-                  return_window_rd: bool = False):
+                  return_window_rd: bool = False,
+                  profile: StageProfile | None = None):
     """Replay one window for every tenant at once (exact, vectorized).
 
     Mirrors ``simulate()`` per tenant: when ``caches[k]`` is given its
@@ -916,7 +919,16 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
     window-internal reuses, so the Analyzer gets its reuse distances for
     free from the same counting pass; ``None`` where the tenant was not
     replayed on the tape (empty window or zero capacity).
+
+    ``profile`` tiles the call with stages: ``replay_tape`` (the tape,
+    its occurrence links and warm prefixes), ``replay_live_counts`` and
+    ``replay_ro_tokens`` (the RO guard and the token replays, where a
+    tenant is RO), ``replay_residency`` (the counting pass and its fetch)
+    and ``replay_account`` (hits, latency, dirty flags, final state).  It
+    counts a sync per device program whose outputs the host waits on,
+    and under ``ro_tokens`` the length of each tape a token replay walks.
     """
+    _pstep(profile, "replay_tape")
     if t_write_bypass is None:
         t_write_bypass = 1.2 * t_fast
     if t_fast2 is None:
@@ -972,6 +984,7 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
 
     rds: list[np.ndarray | None] = [None] * T
     if not vec:
+        _pstep(profile, None)
         return (results, rds) if return_window_rd else results
 
     # restore the hierarchy invariant before reading warm state (both
@@ -1093,13 +1106,17 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
     tokens: dict[int, tuple[np.ndarray, np.ndarray, int]] = {}
     tokens2: dict[int, tuple[np.ndarray, np.ndarray, np.ndarray,
                              int, int]] = {}
+    accel = _accel_default()
     if np.any(pol_codes == 2):
+        _pstep(profile, "replay_live_counts")
         w2 = np.flatnonzero(pos < l2end_of)      # warm-L2 pseudo positions
-        if _accel_default():
+        if accel:
             from repro.kernels.cache_sim.ops import ro_live_counts_accel
             L = ro_live_counts_accel(nxt_c, is_read)
             U2 = (ro_live_counts_accel(nxt_c, pos < l2end_of)
                   if w2.size else None)
+            if profile is not None:
+                profile.sync(2 if w2.size else 1)
         else:
             d = (np.bincount(np.flatnonzero(is_read), minlength=m + 1)
                  - np.bincount(nxt_c[is_read], minlength=m + 1))
@@ -1110,9 +1127,9 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
                 U2 = np.cumsum(du[:m])
             else:
                 U2 = None
-        token_replay = (ro_token_replay_device if _accel_default()
-                        else _ro_token_replay)
-        token_replay2 = (ro_token_replay_levels_device if _accel_default()
+        _pstep(profile, "replay_ro_tokens")
+        token_replay = ro_token_replay_device if accel else _ro_token_replay
+        token_replay2 = (ro_token_replay_levels_device if accel
                          else _ro_token_replay_levels)
         for t, k in enumerate(vec):
             if pol_codes[t] != 2:
@@ -1133,13 +1150,18 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
                     tokens[t] = token_replay(
                         is_read[s:e], prev[s:e] - s, nxt_c[s:e] - s,
                         force_dirty[s:e], ro_cap)
+                if profile is not None:
+                    profile.count("ro_tokens", e - s)
+                    if accel:
+                        profile.sync()
 
     # -------------------------------------------------- residency oracle
     # (the kernel's counting window (prev[i], i) never crosses a tenant
     # block for hot accesses and cold rows are masked, so the whole tape
     # goes through one kernel launch on TPU)
+    _pstep(profile, "replay_residency")
     sd = level_masks = None
-    if _accel_default():
+    if accel:
         from repro.kernels.cache_sim.ops import (residency_levels_accel,
                                                  stack_distances_accel)
         if return_window_rd:
@@ -1148,9 +1170,12 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
             # both-level residency straight off the kernel, one launch
             level_masks = residency_levels_accel(prev, nxt_c,
                                                  cap1_of, captot_of)
+        if profile is not None:
+            profile.sync()
     else:
         sd = _stack_distances_host(prev, nxt_c,
                                    bounds=np.concatenate([starts_a, [m]]))
+    _pstep(profile, "replay_account")
     if return_window_rd:
         # window-internal reuse distances: reuses whose previous occurrence
         # is a warm-prefix pseudo-access are cold from the Analyzer's view
@@ -1360,6 +1385,7 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
                         d2k = np.zeros(d2k.size, bool)
                     c2v.set_state_arrays(orig_addr[js[in_l2]], d2k)
         results[k] = r
+    _pstep(profile, None)
     return (results, rds) if return_window_rd else results
 
 

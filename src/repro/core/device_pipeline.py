@@ -53,7 +53,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -62,58 +61,13 @@ from jax import lax
 
 from repro.core.batch_sim import padded_segment_layout, padded_tape_links
 from repro.core.mrc import BatchedHitRatioFunctions
+from repro.core.stage_profile import StageProfile, _pstage
 from repro.kernels.cache_sim.ops import (_on_tpu, segment_counts_device,
                                          width_groups_of)
 
 __all__ = ["StageProfile", "WindowIngest", "WindowDecision",
            "DeviceWindowPipeline", "greedy_walk_device", "ingest_window",
            "monitor_window_device", "transfer_sanitizer"]
-
-
-# --------------------------------------------------------------- profiling
-class StageProfile:
-    """Per-stage wall time + host-sync counter for the window pipelines.
-
-    ``sync()`` marks one host synchronization (a blocking fetch or an
-    explicit ``jax.block_until_ready`` fence); ``stage(name)`` times a
-    stage.  With ``staged=True`` the device pipeline runs its stages as
-    separate launches with a fence after each — attributing wall time per
-    stage at the cost of extra syncs; the default fused mode performs
-    exactly **one** sync per window (the result fetch), which
-    ``syncs_per_window`` exposes for the ≤1-sync assertion.
-    """
-
-    def __init__(self, staged: bool = False):
-        self.staged = bool(staged)
-        self.times: dict[str, float] = {}
-        self.syncs = 0
-        self.windows = 0
-
-    def sync(self, k: int = 1) -> None:
-        self.syncs += k
-
-    @contextlib.contextmanager
-    def stage(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.times[name] = (self.times.get(name, 0.0)
-                                + time.perf_counter() - t0)
-
-    @property
-    def syncs_per_window(self) -> float:
-        return self.syncs / max(self.windows, 1)
-
-    def report(self) -> dict:
-        return {"times_s": dict(self.times), "syncs": self.syncs,
-                "windows": self.windows,
-                "syncs_per_window": self.syncs_per_window}
-
-
-def _pstage(profile: StageProfile | None, name: str):
-    return profile.stage(name) if profile is not None \
-        else contextlib.nullcontext()
 
 
 # ------------------------------------------------------------ dtype plumbing
@@ -527,7 +481,7 @@ def monitor_window_device(addrs: np.ndarray, is_read: np.ndarray,
                         kind=kind, use_kernel=use_kernel, f64=f64,
                         profile=profile)
     if profile is not None:
-        profile.windows += 1
+        profile.end_window()
     if launch_hook is not None:
         launch_hook()
     if ing is None:
@@ -672,7 +626,7 @@ class DeviceWindowPipeline:
         curves = BatchedHitRatioFunctions.from_padded(
             edges_p, hgt_p, kcnt, ing.row_start, ing.n_acc)
         if profile is not None:
-            profile.windows += 1
+            profile.end_window()
         return WindowDecision(np.asarray(sizes, np.int64),
                               np.asarray(wr, np.float64),
                               np.asarray(urd, np.int64),
@@ -685,7 +639,7 @@ class DeviceWindowPipeline:
         ing, n, n_acc = self.ingest(traces, profile)
         if ing is None:
             if profile is not None:
-                profile.windows += 1
+                profile.end_window()
             return self._trivial(n, n_acc)
         out = self._dispatch(ing, profile)
         return self._finish(ing, out, profile)
@@ -710,7 +664,7 @@ class DeviceWindowPipeline:
                 results.append(self._finish(*pending, profile))
             if ing is None:
                 if profile is not None:
-                    profile.windows += 1
+                    profile.end_window()
                 results.append(self._trivial(n, n_acc))
             pending = nxt
         if pending is not None:

@@ -84,6 +84,7 @@ from repro.core.mrc import HitRatioFunction
 from repro.core.partitioner import (PartitionResult, greedy_allocate,
                                     pgd_solve, two_level_solve)
 from repro.core.simulator import LRUCache, SimResult, simulate
+from repro.core.stage_profile import StageProfile, _pstage
 from repro.core.trace import (Trace, TraceError, validate_trace,
                               validate_trace_arrays)
 from repro.core.write_policy import WritePolicy
@@ -260,6 +261,10 @@ class ECICacheManager:
     loses (counted in ``dirty_loss``), so a tier with a fresh failure
     history must serve writes through a clean policy until trust is
     re-established.
+
+    ``profile`` (a ``StageProfile``, default off) records every window's
+    stages, counters and host syncs, and puts each stage on the
+    profiler's host plane (see ``run_window``).
     """
 
     def __init__(self, capacity: int, tenant_names: list[str],
@@ -289,7 +294,8 @@ class ECICacheManager:
                  fault_tolerant: bool | None = None,
                  retry_limit: int = 2, backoff_base: float = 0.0,
                  demote_cooldown: int = 2,
-                 demote_policy: WritePolicy | str = WritePolicy.WT):
+                 demote_policy: WritePolicy | str = WritePolicy.WT,
+                 profile: StageProfile | None = None):
         if engine not in ("batch", "lru"):
             raise ValueError(f"engine must be 'batch' or 'lru', got {engine!r}")
         if pipeline not in ("host", "device", "sharded"):
@@ -387,6 +393,8 @@ class ECICacheManager:
         self.poisoned_windows = 0
         self.straggler_windows = 0
         self.degrade_events = 0
+        # stage times, counters and host syncs of every window (None: off)
+        self.profile = profile
 
     # ------------------------------------------------------------- Monitor
     def record(self, tenant: int, addrs: np.ndarray, is_read: np.ndarray) -> None:
@@ -469,7 +477,8 @@ class ECICacheManager:
                 m = analyze_windows([trace], **{**kw, "tenant_ids": [i]},
                                     pipeline="host",
                                     fault_hook=self._launch_hook(
-                                        win, "tenant", 0))
+                                        win, "tenant", 0),
+                                    profile=self.profile)
             except Exception:
                 self._held.add(i)
                 self.tenant_quarantines += 1
@@ -509,7 +518,7 @@ class ECICacheManager:
         kw = self._monitor_kwargs(act)
         if not self.fault_tolerant:
             mon = analyze_windows(traces, precomputed_trd=pre,
-                                  pipeline=pipe, **kw)
+                                  pipeline=pipe, profile=self.profile, **kw)
             self.windows_analyzed += 1
             return mon, act, pipe
         win = self._cur_window
@@ -537,7 +546,7 @@ class ECICacheManager:
                         pipeline=(rung if rung in ("sharded", "device")
                                   else "host"),
                         fault_hook=self._launch_hook(win, rung, attempt),
-                        **kw)
+                        profile=self.profile, **kw)
                     self.windows_analyzed += 1
                     return mon, act, rung
                 except TraceError:
@@ -633,10 +642,11 @@ class ECICacheManager:
                 # budget exactly (the PGD box projection cannot go below
                 # its floors)
                 pfn = greedy_allocate
-        part, part2 = two_level_solve(
-            mon.curves, cap1, cap2, self.t_fast,
-            self.t_fast2, self.t_slow, c_min=self.c_min,
-            partition_fn=pfn)
+        with _pstage(self.profile, "partition"):
+            part, part2 = two_level_solve(
+                mon.curves, cap1, cap2, self.t_fast,
+                self.t_fast2, self.t_slow, c_min=self.c_min,
+                partition_fn=pfn)
 
         n_ten = len(self.tenants)
         sizes_full = np.zeros(n_ten, dtype=np.int64)
@@ -696,7 +706,8 @@ class ECICacheManager:
         # guard rollback point: a quarantined decision must not leak the
         # corrupted pass's Alg.-3 policy flips
         pol_snap = [(t.policy, t.policy2) for t in self.tenants]
-        mon, act, rung = self._monitor_ladder(act, window_trd)
+        with _pstage(self.profile, "monitor"):
+            mon, act, rung = self._monitor_ladder(act, window_trd)
         try:
             if mon is None:
                 return self._fallback_decision(trigger, "monitor_outage")
@@ -720,10 +731,12 @@ class ECICacheManager:
                         try:
                             kw = {**self._monitor_kwargs(act),
                                   "sample_rate": None}
-                            mon2 = analyze_windows(
-                                [self.tenants[i].window_trace()
-                                 for i in act],
-                                pipeline="host", **kw)
+                            with _pstage(self.profile, "monitor"):
+                                mon2 = analyze_windows(
+                                    [self.tenants[i].window_trace()
+                                     for i in act],
+                                    pipeline="host", profile=self.profile,
+                                    **kw)
                             self.windows_analyzed += 1
                             if self.faults is not None:
                                 self.faults.corrupt_monitor(
@@ -921,7 +934,19 @@ class ECICacheManager:
         phase detector, a churn event, or the ``reconfig_interval`` clock
         triggers it (see the class docstring); the replay half always
         runs.
+
+        With a ``profile`` the window is stage ``window``, with stages
+        ``replay`` (``simulate_many``'s ``replay_*`` stages inside),
+        ``monitor`` and ``partition`` in it; everything else of the window
+        is ``window``'s self time.
         """
+        with _pstage(self.profile, "window"):
+            self._run_window(traces, engine)
+        if self.profile is not None:
+            self.profile.end_window()
+
+    def _run_window(self, traces: list[Trace | None],
+                    engine: str | None) -> None:
         engine = self.engine if engine is None else engine
         win = self.windows_run
         self._cur_window = win
@@ -939,31 +964,34 @@ class ECICacheManager:
 
         window_trd: dict[int, np.ndarray] | None = None
         if engine == "batch":
-            results, rds = simulate_many(
-                [traces[i] for i in idx],
-                policies=[self.tenants[i].policy for i in idx],
-                t_fast=self.t_fast, t_slow=self.t_slow,
-                t_write_bypass=self.t_write_bypass,
-                flush_cost=self.flush_cost,
-                caches=[self.tenants[i].cache for i in idx],
-                policies2=[self.tenants[i].policy2 for i in idx],
-                caches2=[self.tenants[i].cache2 for i in idx],
-                t_fast2=self.t_fast2,
-                return_window_rd=True)
+            with _pstage(self.profile, "replay"):
+                results, rds = simulate_many(
+                    [traces[i] for i in idx],
+                    policies=[self.tenants[i].policy for i in idx],
+                    t_fast=self.t_fast, t_slow=self.t_slow,
+                    t_write_bypass=self.t_write_bypass,
+                    flush_cost=self.flush_cost,
+                    caches=[self.tenants[i].cache for i in idx],
+                    policies2=[self.tenants[i].policy2 for i in idx],
+                    caches2=[self.tenants[i].cache2 for i in idx],
+                    t_fast2=self.t_fast2,
+                    return_window_rd=True, profile=self.profile)
             window_trd = {i: rd for i, rd in zip(idx, rds) if rd is not None}
             for i, res in zip(idx, results):
                 self._accumulate(self.tenants[i], res)
             self.ro_fallback_windows += sum(r.fallback for r in results)
         else:
-            for i in idx:
-                t = self.tenants[i]
-                res = simulate(traces[i], t.cache.capacity, t.policy,
-                               self.t_fast, self.t_slow,
-                               t_write_bypass=self.t_write_bypass,
-                               flush_cost=self.flush_cost, cache=t.cache,
-                               capacity2=t.cache2.capacity, policy2=t.policy2,
-                               t_fast2=self.t_fast2, cache2=t.cache2)
-                self._accumulate(t, res)
+            with _pstage(self.profile, "replay"):
+                for i in idx:
+                    t = self.tenants[i]
+                    res = simulate(traces[i], t.cache.capacity, t.policy,
+                                   self.t_fast, self.t_slow,
+                                   t_write_bypass=self.t_write_bypass,
+                                   flush_cost=self.flush_cost, cache=t.cache,
+                                   capacity2=t.cache2.capacity,
+                                   policy2=t.policy2, t_fast2=self.t_fast2,
+                                   cache2=t.cache2)
+                    self._accumulate(t, res)
         self.tenant_windows += len(idx)
         self.windows_run += 1
 

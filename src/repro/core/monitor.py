@@ -113,7 +113,6 @@ tested in ``tests/test_monitor_scale.py``.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 
 import numpy as np
@@ -123,6 +122,7 @@ from repro.core.batch_sim import (_accel_default, _stack_distances_host,
 from repro.core.mrc import BatchedHitRatioFunctions, build_hit_ratio_functions
 from repro.core.reuse_distance import (auto_sample_rate, shards_keep_mask,
                                        shards_salt)
+from repro.core.stage_profile import _pstage
 from repro.core.trace import Trace, validate_trace
 
 __all__ = ["MonitorResult", "analyze_windows"]
@@ -227,12 +227,6 @@ def _segment_links(addrs: np.ndarray, tid: np.ndarray,
     return prev, nxt
 
 
-def _pstage(profile, name: str):
-    """Profile a host-pipeline stage (no-op without a ``StageProfile``)."""
-    return (profile.stage(name) if profile is not None
-            else contextlib.nullcontext())
-
-
 def _sd_pass(prev: np.ndarray, nxt_c: np.ndarray, backend: str,
              bounds: np.ndarray | None = None,
              layout=None, profile=None) -> np.ndarray:
@@ -290,7 +284,7 @@ def analyze_windows(traces: list[Trace], kind: str = "urd",
     program (one jit, one host sync — requires ``percentile == 100``);
     ``pipeline="sharded"`` through its ``shard_map`` twin over the
     default control-plane mesh (same requirement, one sync per mesh);
-    ``profile`` (a ``device_pipeline.StageProfile``) records per-stage
+    ``profile`` (a ``stage_profile.StageProfile``) records per-stage
     times and host syncs on any pipeline.
 
     ``validate=True`` checks every tape against the ingest contract first
@@ -393,7 +387,7 @@ def analyze_windows(traces: list[Trace], kind: str = "urd",
                                                mask=smask)
             urd = _urd_sizes(dist, tid, n, bounds, percentile, curves)
         if profile is not None:
-            profile.windows += 1
+            profile.end_window()
         return MonitorResult(curves, urd, wr, np.ones(n),
                              np.zeros(n), kind)
 
@@ -462,7 +456,7 @@ def analyze_windows(traces: list[Trace], kind: str = "urd",
             # surviving addresses
             distinct = np.bincount(tid_s[prev < 0], minlength=n)
         if profile is not None:
-            profile.windows += 1
+            profile.end_window()
     errors = np.where(rates < 1.0,
                       np.minimum(1.0,
                                  1.0 / np.sqrt(np.maximum(distinct, 1))),

@@ -64,11 +64,11 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.batch_sim import padded_segment_layout, padded_tape_links
-from repro.core.device_pipeline import (StageProfile, _f64_default, _fetch,
-                                        _np_dtypes, _programs, _pstage,
-                                        _trivial_monitor, _x64,
+from repro.core.device_pipeline import (_f64_default, _fetch, _np_dtypes,
+                                        _programs, _trivial_monitor, _x64,
                                         transfer_sanitizer)
 from repro.core.mrc import BatchedHitRatioFunctions
+from repro.core.stage_profile import StageProfile, _pstage
 from repro.kernels.cache_sim.ops import _on_tpu
 
 __all__ = ["ShardIngest", "ShardLayout", "dispatch_decision_sharded",
@@ -418,7 +418,7 @@ def monitor_window_sharded(addrs: np.ndarray, is_read: np.ndarray,
                                 use_kernel=use_kernel, f64=f64,
                                 profile=profile)
     if profile is not None:
-        profile.windows += 1
+        profile.end_window()
     if launch_hook is not None:
         launch_hook()
     if ing is None:

@@ -190,7 +190,7 @@ def stack_distances_segments_accel(prev: np.ndarray, nxt: np.ndarray,
     unrestricted launch covers the whole tape, exactly like the batch
     replay engine's tape.
 
-    ``profile`` (a ``device_pipeline.StageProfile``) records one host
+    ``profile`` (a ``core.stage_profile.StageProfile``) records one host
     sync per width launch — the per-window sync count this path pays and
     the fused device program eliminates.
     """
