@@ -3,11 +3,8 @@
 Public API re-exports for the paper's primary contribution.
 """
 from repro.core.baselines import GlobalLRUManager, make_manager
-from repro.core.batch_sim import (reuse_distances_fast,
-                                  ro_token_replay_device,
-                                  ro_token_replay_levels_device,
-                                  simulate_batch, simulate_many,
-                                  stack_distances)
+from repro.core.batch_sim import (reuse_distances_fast, simulate_batch,
+                                  simulate_many, stack_distances)
 from repro.core.characterize import (PhaseDetector, PhaseEvent,
                                      WindowFeatures, characterize_trace,
                                      characterize_windows)
@@ -60,7 +57,6 @@ __all__ = [
     "monitor_window_device", "pgd_solve",
     "rebalance_levels", "request_type_mix", "reuse_distances",
     "reuse_distances_fast", "reuse_distances_vectorized",
-    "ro_token_replay_device", "ro_token_replay_levels_device",
     "sampled_reuse_distances", "shards_salt",
     "simulate", "simulate_batch", "simulate_many", "stack_distances",
     "total_cache_writes_wb", "transfer_sanitizer", "two_level_solve",

@@ -52,8 +52,8 @@ capacity eviction has occurred.  Counterexample at C=2 for trace
 ``r(c)``, so the final read misses, but after the two invalidations only
 zero live blocks separate ``r(a)`` from its reuse, so any distance oracle
 says hit.  The engine therefore computes the *live count*
-``L(t) = #{ j <= t : is_read[j], nxt[j] > t }`` (an O(n) cumsum — numpy on
-host, the ``cache_sim`` live-count op on-device on TPU); when
+``L(t) = #{ j <= t : is_read[j], nxt[j] > t }`` (an O(n) cumsum on the
+host, ``_live_counts``); when
 ``max L <= C`` the cache never fills, no eviction can occur, and
 ``resident ⟺ live`` is exact — otherwise that tenant's window is replayed
 by the O(n) *eviction-token* loop (below).  WB/WT never need the guard
@@ -120,12 +120,12 @@ only if L2 then overflows (the final eviction, flushed when dirty; with a
 clean ``policy2`` the flush happens at the demotion boundary instead and
 the token's dirty flag clears).  Afterwards every residency question is
 vectorized exactly as in the single-level case, plus ``lvl[prev]`` splits
-hits by level.  Both the single-level loop and this two-level
-generalization have ``lax.fori_loop`` on-device ports
-(``ro_token_replay_device`` / ``ro_token_replay_levels_device``), used
-automatically on TPU hosts; the interpreter remains only for genuinely
-degenerate windows (empty two-level windows, or warm L2 behind a dead
-``C2 <= 0`` level), counted by ``SimResult.fallback``.
+hits by level.  Both token loops run on the host on every backend; the
+interpreter remains only for genuinely degenerate windows (empty two-level
+windows, or warm L2 behind a dead ``C2 <= 0`` level), counted by
+``SimResult.fallback``.  A step is a scalar pointer chase with no parallel
+width: as a ``lax.fori_loop`` it took 4.97 µs of device time on a TPU v5e,
+against 0.21 µs in the host loop.
 
 On TPU the ``SD`` counting runs on-accelerator via the
 ``repro.kernels.cache_sim`` Pallas kernel (the occupancy-masked
@@ -150,8 +150,6 @@ __all__ = [
     "padded_segment_layout",
     "stack_distances",
     "reuse_distances_fast",
-    "ro_token_replay_device",
-    "ro_token_replay_levels_device",
     "simulate_batch",
     "simulate_many",
 ]
@@ -534,6 +532,20 @@ def reuse_distances_fast(trace: Trace, kind: str = "urd",
 
 
 # ------------------------------------------------------------ batch replay
+def _live_counts(nxt: np.ndarray, occ_at: np.ndarray) -> np.ndarray:
+    """``L[i] = #{j <= i : j in occ_at, nxt[j] > i}``: the blocks live after
+    access ``i`` assuming no eviction, for the RO guard.
+
+    Each occupying token is the interval ``[j, nxt[j])``: +1 at its birth,
+    -1 at its death, prefix-summed (``nxt[j] > j``, so a token dead by
+    ``i`` was born by ``i``).
+    """
+    m = nxt.shape[0]
+    d = (np.bincount(occ_at, minlength=m + 1)
+         - np.bincount(nxt[occ_at], minlength=m + 1))
+    return np.cumsum(d[:m])
+
+
 def _ro_token_replay(is_read_blk: np.ndarray, prev_blk: np.ndarray,
                      nxt_blk: np.ndarray, force_blk: np.ndarray,
                      cap: int) -> tuple[np.ndarray, np.ndarray, int]:
@@ -662,222 +674,6 @@ def _ro_token_replay_levels(is_read_blk: np.ndarray, prev_blk: np.ndarray,
     return (np.asarray(death, dtype=np.int64),
             np.asarray(dirty, dtype=bool),
             np.asarray(lvl, dtype=np.int8), flushes, demotions)
-
-
-_RO_DEVICE_JIT = None
-_RO_LEVELS_DEVICE_JIT = None
-
-
-def _ro_device_core():
-    """Build (and cache) the jitted sequential token-replay loop."""
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def ro_token_replay(rd, pv, nxt, force, cap):
-        n = rd.shape[0]
-
-        def body(t, carry):
-            death, dirty, fl, res, b = carry
-            p = pv[t]
-            ps = jnp.maximum(p, 0)
-            hit = (p >= 0) & rd[ps] & (death[ps] == t)
-
-            def read_case(c):
-                death, dirty, fl, res, b = c
-
-                def on_hit(c):
-                    death, dirty, fl, res, b = c
-                    return (death, dirty.at[t].set(dirty[ps]), fl, res, b)
-
-                def on_miss(c):
-                    death, dirty, fl, res, b = c
-                    res = res + 1
-
-                    def evict(c):
-                        death, dirty, fl, res, b = c
-                        b = jax.lax.while_loop(
-                            lambda bb: (~rd[bb]) | (death[bb] <= t),
-                            lambda bb: bb + 1, b)
-                        fl = fl + dirty[b].astype(jnp.int32)
-                        return (death.at[b].set(t), dirty, fl, res - 1, b)
-
-                    return jax.lax.cond(res > cap, evict, lambda c: c,
-                                        (death, dirty, fl, res, b))
-
-                return jax.lax.cond(hit, on_hit, on_miss, c)
-
-            def write_case(c):
-                death, dirty, fl, res, b = c
-                return (death, dirty, fl, res - hit.astype(jnp.int32), b)
-
-            return jax.lax.cond(rd[t], read_case, write_case, carry)
-
-        death0 = nxt.astype(jnp.int32)
-        carry = (death0, force, jnp.int32(0), jnp.int32(0), jnp.int32(0))
-        death, dirty, fl, _, _ = jax.lax.fori_loop(0, n, body, carry)
-        return death, dirty, fl
-
-    return ro_token_replay
-
-
-def ro_token_replay_device(is_read_blk: np.ndarray, prev_blk: np.ndarray,
-                           nxt_blk: np.ndarray, force_blk: np.ndarray,
-                           cap: int) -> tuple[np.ndarray, np.ndarray, int]:
-    """``_ro_token_replay`` as a ``lax.fori_loop`` sequential device pass.
-
-    Same token formulation, same outputs (the host loop stays the oracle —
-    equivalence-tested on randomized RO-pressure traces); the whole replay
-    is one fori_loop with an inner while advancing the bottom pointer, so
-    RO tenants under eviction pressure stay on-device on TPU hosts.  Inputs
-    are padded to a multiple of 64 with no-op writes (``prev = -1``) to
-    bound jit retraces across window lengths.
-    """
-    import jax.numpy as jnp
-    global _RO_DEVICE_JIT
-    if _RO_DEVICE_JIT is None:
-        _RO_DEVICE_JIT = _ro_device_core()
-    n = int(is_read_blk.shape[0])
-    if n == 0:
-        return (np.zeros(0, np.int64), np.zeros(0, bool), 0)
-    pad = (-n) % 64
-    rd = np.pad(is_read_blk.astype(bool), (0, pad), constant_values=False)
-    pv = np.pad(prev_blk.astype(np.int32), (0, pad), constant_values=-1)
-    nx = np.pad(nxt_blk.astype(np.int32), (0, pad), constant_values=n + pad)
-    fc = np.pad(force_blk.astype(bool), (0, pad), constant_values=False)
-    death, dirty, fl = _RO_DEVICE_JIT(jnp.asarray(rd), jnp.asarray(pv),
-                                      jnp.asarray(nx), jnp.asarray(fc),
-                                      jnp.int32(cap))
-    death = np.asarray(death)[:n].astype(np.int64)
-    # padded positions never evict, so real token deaths are unaffected,
-    # but clamp natural deaths back to the unpadded horizon
-    death = np.minimum(death, nxt_blk.astype(np.int64))
-    return death, np.asarray(dirty)[:n].astype(bool), int(fl)
-
-
-def _ro_levels_device_core():
-    """Build (and cache) the jitted two-level token-replay loop."""
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def ro_token_replay_levels(rd, pv, nxt, force, cap1, cap2, l2_end,
-                               clean2):
-        n = rd.shape[0]
-
-        def body(t, carry):
-            death, dirty, lvl, fl, res1, res2, b1, b2, dem = carry
-            p = pv[t]
-            ps = jnp.maximum(p, 0)
-            hit = (p >= 0) & rd[ps] & (death[ps] == t)
-            hit1 = hit & (lvl[ps] == 1)
-            hit2 = hit & (lvl[ps] == 2)
-
-            def warm_l2(c):
-                death, dirty, lvl, fl, res1, res2, b1, b2, dem = c
-                return (death, dirty, lvl.at[t].set(2), fl,
-                        res1, res2 + 1, b1, b2, dem)
-
-            def demote(c):
-                death, dirty, lvl, fl, res1, res2, b1, b2, dem = c
-                b1 = jax.lax.while_loop(
-                    lambda b: (~rd[b]) | (death[b] <= t) | (lvl[b] == 2),
-                    lambda b: b + 1, b1)
-                fl = fl + (clean2 & dirty[b1]).astype(jnp.int32)
-                dirty = dirty.at[b1].set(dirty[b1] & ~clean2)
-                lvl = lvl.at[b1].set(2)
-                c2s = (death, dirty, fl, res2 + 1, b2)
-
-                def evict2(c2):
-                    death, dirty, fl, res2, b2 = c2
-                    b2 = jax.lax.while_loop(
-                        lambda b: (~rd[b]) | (death[b] <= t),
-                        lambda b: b + 1, b2)
-                    fl = fl + dirty[b2].astype(jnp.int32)
-                    return (death.at[b2].set(t), dirty, fl, res2 - 1, b2)
-
-                death, dirty, fl, res2, b2 = jax.lax.cond(
-                    res2 + 1 > cap2, evict2, lambda c2: c2, c2s)
-                return (death, dirty, lvl, fl, res1 - 1, res2, b1, b2,
-                        dem + 1)
-
-            def read_case(c):
-                def on_hit1(c):
-                    death, dirty, lvl, fl, res1, res2, b1, b2, dem = c
-                    return (death, dirty.at[t].set(dirty[ps]), lvl, fl,
-                            res1, res2, b1, b2, dem)
-
-                def on_other(c):
-                    # promotion (hit2) or miss: a new token born in L1
-                    death, dirty, lvl, fl, res1, res2, b1, b2, dem = c
-                    dirty = dirty.at[t].set(
-                        jnp.where(hit2, dirty[ps], dirty[t]))
-                    res1 = res1 + 1
-                    res2 = res2 - hit2.astype(jnp.int32)
-                    c = (death, dirty, lvl, fl, res1, res2, b1, b2, dem)
-                    return jax.lax.cond(res1 > cap1, demote,
-                                        lambda c: c, c)
-
-                return jax.lax.cond(hit1, on_hit1, on_other, c)
-
-            def write_case(c):
-                death, dirty, lvl, fl, res1, res2, b1, b2, dem = c
-                return (death, dirty, lvl, fl,
-                        res1 - hit1.astype(jnp.int32),
-                        res2 - hit2.astype(jnp.int32), b1, b2, dem)
-
-            def window(c):
-                return jax.lax.cond(rd[t], read_case, write_case, c)
-
-            return jax.lax.cond(t < l2_end, warm_l2, window, carry)
-
-        carry = (nxt.astype(jnp.int32), force, jnp.ones(n, jnp.int32),
-                 jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                 jnp.int32(0), jnp.int32(0), jnp.int32(0))
-        death, dirty, lvl, fl, _, _, _, _, dem = jax.lax.fori_loop(
-            0, n, body, carry)
-        return death, dirty, lvl, fl, dem
-
-    return ro_token_replay_levels
-
-
-def ro_token_replay_levels_device(is_read_blk: np.ndarray,
-                                  prev_blk: np.ndarray, nxt_blk: np.ndarray,
-                                  force_blk: np.ndarray, cap1: int,
-                                  cap2: int, l2_end: int, clean2: bool
-                                  ) -> tuple[np.ndarray, np.ndarray,
-                                             np.ndarray, int, int]:
-    """``_ro_token_replay_levels`` as a ``lax.fori_loop`` device pass.
-
-    Same token formulation, same outputs (the host loop stays the oracle —
-    equivalence-tested on randomized two-level RO-pressure traces), so
-    two-level RO tenants under eviction pressure stay on-device on TPU
-    hosts.  Inputs are padded to a multiple of 64 with no-op writes
-    (``prev = -1``) to bound jit retraces across window lengths.
-    """
-    import jax.numpy as jnp
-    global _RO_LEVELS_DEVICE_JIT
-    if _RO_LEVELS_DEVICE_JIT is None:
-        _RO_LEVELS_DEVICE_JIT = _ro_levels_device_core()
-    n = int(is_read_blk.shape[0])
-    if n == 0:
-        return (np.zeros(0, np.int64), np.zeros(0, bool),
-                np.zeros(0, np.int8), 0, 0)
-    pad = (-n) % 64
-    rd = np.pad(is_read_blk.astype(bool), (0, pad), constant_values=False)
-    pv = np.pad(prev_blk.astype(np.int32), (0, pad), constant_values=-1)
-    nx = np.pad(nxt_blk.astype(np.int32), (0, pad), constant_values=n + pad)
-    fc = np.pad(force_blk.astype(bool), (0, pad), constant_values=False)
-    death, dirty, lvl, fl, dem = _RO_LEVELS_DEVICE_JIT(
-        jnp.asarray(rd), jnp.asarray(pv), jnp.asarray(nx), jnp.asarray(fc),
-        jnp.int32(cap1), jnp.int32(cap2), jnp.int32(l2_end),
-        jnp.asarray(bool(clean2)))
-    death = np.asarray(death)[:n].astype(np.int64)
-    # padded positions never evict, so real token deaths are unaffected,
-    # but clamp natural deaths back to the unpadded horizon
-    death = np.minimum(death, nxt_blk.astype(np.int64))
-    return (death, np.asarray(dirty)[:n].astype(bool),
-            np.asarray(lvl)[:n].astype(np.int8), int(fl), int(dem))
 
 
 def _segment_heads(sorted_vals: np.ndarray) -> np.ndarray:
@@ -1100,37 +896,17 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
     # (``_ro_token_replay_levels``) otherwise — still exact, still
     # loop-free afterwards: the loops only shorten token deaths and
     # transfer levels, and hits are recovered as ``death[prev] == i``
-    # (split per level by ``lvl[prev]``).  Both have fori_loop device
-    # ports used on TPU hosts, where the guard's live counts also stay
-    # on-device (cache_sim's O(n) delta-cumsum live-count op).
+    # (split per level by ``lvl[prev]``).  The guard and the loops run on
+    # the host on every backend.
     tokens: dict[int, tuple[np.ndarray, np.ndarray, int]] = {}
     tokens2: dict[int, tuple[np.ndarray, np.ndarray, np.ndarray,
                              int, int]] = {}
-    accel = _accel_default()
     if np.any(pol_codes == 2):
         _pstep(profile, "replay_live_counts")
+        L = _live_counts(nxt_c, np.flatnonzero(is_read))
         w2 = np.flatnonzero(pos < l2end_of)      # warm-L2 pseudo positions
-        if accel:
-            from repro.kernels.cache_sim.ops import ro_live_counts_accel
-            L = ro_live_counts_accel(nxt_c, is_read)
-            U2 = (ro_live_counts_accel(nxt_c, pos < l2end_of)
-                  if w2.size else None)
-            if profile is not None:
-                profile.sync(2 if w2.size else 1)
-        else:
-            d = (np.bincount(np.flatnonzero(is_read), minlength=m + 1)
-                 - np.bincount(nxt_c[is_read], minlength=m + 1))
-            L = np.cumsum(d[:m])
-            if w2.size:
-                du = (np.bincount(w2, minlength=m + 1)
-                      - np.bincount(nxt_c[w2], minlength=m + 1))
-                U2 = np.cumsum(du[:m])
-            else:
-                U2 = None
+        U2 = _live_counts(nxt_c, w2) if w2.size else None
         _pstep(profile, "replay_ro_tokens")
-        token_replay = ro_token_replay_device if accel else _ro_token_replay
-        token_replay2 = (ro_token_replay_levels_device if accel
-                         else _ro_token_replay_levels)
         for t, k in enumerate(vec):
             if pol_codes[t] != 2:
                 continue
@@ -1142,18 +918,16 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
                 lt = lt - U2[s:e]
             if int(lt.max()) > ro_cap:
                 if cap1 > 0 and cap2 > 0:
-                    tokens2[t] = token_replay2(
+                    tokens2[t] = _ro_token_replay_levels(
                         is_read[s:e], prev[s:e] - s, nxt_c[s:e] - s,
                         force_dirty[s:e], cap1, cap2,
                         int(l2_ends_a[t] - s), bool(clean2_arr[t]))
                 else:
-                    tokens[t] = token_replay(
+                    tokens[t] = _ro_token_replay(
                         is_read[s:e], prev[s:e] - s, nxt_c[s:e] - s,
                         force_dirty[s:e], ro_cap)
                 if profile is not None:
                     profile.count("ro_tokens", e - s)
-                    if accel:
-                        profile.sync()
 
     # -------------------------------------------------- residency oracle
     # (the kernel's counting window (prev[i], i) never crosses a tenant
@@ -1161,7 +935,7 @@ def simulate_many(traces: list[Trace], capacities=None, policies=None, *,
     # goes through one kernel launch on TPU)
     _pstep(profile, "replay_residency")
     sd = level_masks = None
-    if accel:
+    if _accel_default():
         from repro.kernels.cache_sim.ops import (residency_levels_accel,
                                                  stack_distances_accel)
         if return_window_rd:

@@ -250,9 +250,9 @@ def live_count_scan(nxt: jax.Array, occ: jax.Array, *, tile: int = 256,
     counts[i] = #{ j <= i : occ[j], nxt[j] > i } — the RO write-around
     live count (occupying tokens resident after access i assuming no
     eviction).  Same (i, j)-plane layout as ``cache_sim_scan`` with the
-    interval test flipped to "covers i from the left"; this is the
-    accelerator path of the batch engine's RO no-eviction guard, feeding
-    the eviction-token replay dispatch (see ``batch_sim``).
+    interval test flipped to "covers i from the left".  The batch engine's
+    RO guard computes these counts on the host (``batch_sim._live_counts``,
+    an O(n) prefix sum); this kernel is on no serving path.
     """
     n = nxt.shape[0]
     nt = -(-n // tile)

@@ -15,17 +15,15 @@ import numpy as np
 
 from repro.kernels.cache_sim.kernel import (cache_sim_levels_scan,
                                             cache_sim_scan,
-                                            cache_sim_segments_scan,
-                                            live_count_scan)
+                                            cache_sim_segments_scan)
 from repro.kernels.cache_sim.ref import (cache_sim_levels_ref, cache_sim_ref,
                                          cache_sim_segments_ref,
-                                         cache_sim_segments_tree,
-                                         live_counts_delta)
+                                         cache_sim_segments_tree)
 
 __all__ = ["cache_sim_op", "cache_sim_levels_op", "cache_sim_segments_op",
-           "live_count_op", "segment_counts_device", "stack_distances_accel",
-           "residency_levels_accel", "ro_live_counts_accel",
-           "stack_distances_segments_accel", "width_groups_of"]
+           "segment_counts_device", "stack_distances_accel",
+           "residency_levels_accel", "stack_distances_segments_accel",
+           "width_groups_of"]
 
 
 def _on_tpu() -> bool:
@@ -61,13 +59,6 @@ def cache_sim_segments_op(prev, nxt, occ, *, seg_width: int,
         return cache_sim_segments_scan(prev, nxt, occ, seg_width=seg_width,
                                        interpret=not _on_tpu())
     return cache_sim_segments_ref(prev, nxt, occ, seg_width)
-
-
-@partial(jax.jit, static_argnames=("use_kernel",))
-def live_count_op(nxt, occ, *, use_kernel: bool = False):
-    if use_kernel:
-        return live_count_scan(nxt, occ, interpret=not _on_tpu())
-    return live_counts_delta(nxt, occ)
 
 
 def width_groups_of(widths: np.ndarray) -> tuple[tuple[int, int, int], ...]:
@@ -124,30 +115,6 @@ def segment_counts_device(gprev, gnxt, gocc,
     if not parts:
         return jnp.zeros(0, jnp.int32)
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-
-
-def ro_live_counts_accel(nxt: np.ndarray, occ: np.ndarray,
-                         use_kernel: bool = False) -> np.ndarray:
-    """int64 RO live counts ``L[i] = #{ j <= i : occ[j], nxt[j] > i }``.
-
-    The accelerator path of the batch engine's write-around no-eviction
-    guard: with ``occ = is_read`` it yields the live-block count per tape
-    position, with ``occ`` restricted to warm-L2 pseudo positions the
-    still-untouched warm-L2 count (``U2``).  Feeds the eviction-token
-    replay dispatch (``_ro_token_replay`` / ``_ro_token_replay_levels``
-    and their fori_loop device ports) so RO tenants under pressure are
-    detected without leaving the device on TPU hosts.
-
-    Default path is the O(n) delta-cumsum form (``live_counts_delta`` —
-    interval counting is a prefix sum, no (i, j)-plane needed even
-    in-kernel); ``use_kernel=True`` selects the tiled Pallas scan
-    (``live_count_scan``), retained for launches that fuse the guard with
-    the residency counting and as the interpret-mode validation target.
-    """
-    counts = np.asarray(live_count_op(jnp.asarray(nxt, jnp.int32),
-                                      jnp.asarray(occ, jnp.int32),
-                                      use_kernel=use_kernel))
-    return counts.astype(np.int64)
 
 
 def stack_distances_accel(prev: np.ndarray, nxt: np.ndarray,

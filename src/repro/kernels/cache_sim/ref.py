@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["cache_sim_ref", "cache_sim_levels_ref", "cache_sim_segments_ref",
-           "cache_sim_segments_tree", "live_counts_delta", "live_counts_ref"]
+           "cache_sim_segments_tree", "live_counts_ref"]
 
 
 def cache_sim_ref(prev: jax.Array, nxt: jax.Array,
@@ -98,11 +98,10 @@ def live_counts_ref(nxt: jax.Array, occ: jax.Array) -> jax.Array:
 
     The RO write-around *live count*: how many occupying tokens (reads, or
     warm pseudo-reads) are resident after access ``i`` assuming no
-    eviction — the no-eviction guard of the batch engine's RO paths, and
-    the dispatcher feeding the eviction-token replays (host loops or their
-    fori_loop device ports) when the bound is exceeded.  With ``occ``
-    restricted to warm-L2 pseudo positions it counts the still-untouched
-    warm-L2 blocks (the ``U2`` term of the per-level guard).
+    eviction — the batch engine's RO no-eviction guard (computed on the
+    host there, ``batch_sim._live_counts``).  With ``occ`` restricted to
+    warm-L2 pseudo positions it counts the still-untouched warm-L2 blocks
+    (the ``U2`` term of the per-level guard).
     """
     n = nxt.shape[0]
     i_idx = jnp.arange(n)[:, None]
@@ -110,22 +109,6 @@ def live_counts_ref(nxt: jax.Array, occ: jax.Array) -> jax.Array:
     contrib = ((j_idx <= i_idx) & (nxt[None, :] > i_idx)
                & (occ[None, :] > 0))
     return jnp.sum(contrib, axis=1).astype(jnp.int32)
-
-
-def live_counts_delta(nxt: jax.Array, occ: jax.Array) -> jax.Array:
-    """``live_counts_ref`` in O(n): scatter-add interval deltas + cumsum.
-
-    Each occupying token is an interval ``[j, nxt[j])``: +1 at its birth,
-    −1 at its death position, prefix-summed.  (``nxt[j] > j`` always, so a
-    token dead by ``t`` was also born by ``t``.)  This is the production
-    device path of the RO guard — the ``live_count_scan`` Pallas kernel
-    computes the same counts on the tiled (i, j) plane and is kept as the
-    in-kernel variant, validated against both forms.
-    """
-    n = nxt.shape[0]
-    occi = (occ > 0).astype(jnp.int32)
-    ends = jnp.zeros(n + 1, jnp.int32).at[jnp.clip(nxt, 0, n)].add(occi)
-    return jnp.cumsum(occi) - jnp.cumsum(ends[:n])
 
 
 def cache_sim_levels_ref(prev: jax.Array, nxt: jax.Array, occ: jax.Array,

@@ -2,10 +2,9 @@
 interpreter oracle.
 
 The differential suite for the per-level eviction-token formulation
-(``batch_sim._ro_token_replay_levels`` and its ``lax.fori_loop`` device
-port): small capacities + few addresses force invalidation pressure, so
-every window here used to take the per-access interpreter fallback and now
-must replay vectorized — bit-identical per-level hits, write hits, cache
+(``batch_sim._ro_token_replay_levels``): small capacities + few addresses
+force invalidation pressure, so every window here used to take the
+per-access interpreter fallback and now must replay vectorized — bit-identical per-level hits, write hits, cache
 writes (endurance), demotions, flush charges, latency and final per-level
 LRU states, cold and across warm multi-window chains, with clean and
 dirty-accepting L2 policies.  ``SimResult.fallback`` must stay 0
@@ -18,9 +17,7 @@ from hypothesis import given, settings, strategies as st
 
 from oracle import (EngineDiff, assert_results_equal, examples, mk_trace,
                     trace_strategy)
-from repro.core import (Trace, WritePolicy, make_manager,
-                        ro_token_replay_levels_device, simulate_batch)
-from repro.core.batch_sim import _ro_token_replay_levels
+from repro.core import Trace, WritePolicy, make_manager, simulate_batch
 from repro.core.simulator import LRUCache
 from repro.core.trace import prev_next_occurrence
 
@@ -56,36 +53,6 @@ def test_ro_pressure_warm_chain_matches_interpreter(windows, c1, c2, p2):
     for w, tl in enumerate(windows):
         pol = WritePolicy.WB if w == 0 else WritePolicy.RO
         diff.run_window([mk_trace(tl)], policies=[pol])
-
-
-# --------------------------------------------- device port ≡ host oracle
-@settings(max_examples=examples(60), deadline=None)
-@given(ro_strategy(max_n=80, max_addr=5), st.integers(0, 3),
-       st.integers(0, 3), st.integers(1, 4), st.integers(1, 4),
-       st.booleans())
-def test_ro_levels_device_matches_host(trace_list, n_l2, n_l1, c1, c2,
-                                       clean2):
-    """The fori_loop port must reproduce death/dirty/level/flush/demotion
-    outputs exactly, including warm-L2 and warm-L1 pseudo-read prefixes."""
-    t = mk_trace(trace_list)
-    n_l2, n_l1 = min(n_l2, c2), min(n_l1, c1)
-    warm = np.arange(100, 100 + n_l2 + n_l1, dtype=np.int64)
-    addrs = np.concatenate([warm, t.addrs])
-    rd = np.concatenate([np.ones(warm.size, bool), t.is_read])
-    n = addrs.size
-    if n == 0:
-        return
-    prev, nxt = prev_next_occurrence(addrs)
-    nxt = np.minimum(nxt, n)
-    force = np.zeros(n, bool)
-    force[:warm.size] = (np.arange(warm.size) % 2) == 0
-    args = (rd, prev, nxt, force, c1, c2, n_l2, clean2)
-    dh, yh, lh, fh, mh = _ro_token_replay_levels(*args)
-    dd, yd, ld, fd, md = ro_token_replay_levels_device(*args)
-    assert np.array_equal(dh, dd)
-    assert np.array_equal(yh, yd)
-    assert np.array_equal(lh, ld)
-    assert (fh, mh) == (fd, md)
 
 
 # ------------------------------------------- per-level flush accounting
@@ -168,35 +135,36 @@ def test_degenerate_windows_still_count_as_fallback():
     assert simulate_batch(t, 1, WritePolicy.RO, capacity2=1).fallback == 0
 
 
-# ------------------------------------------------- kernel live-count scan
+# ------------------------------------------------------ RO guard counts
+def _live_count_case(seed, n, space, p_occ):
+    rng = np.random.default_rng(seed)
+    addrs = rng.integers(0, space, n).astype(np.int64)
+    occ = rng.random(n) < p_occ
+    _, nxt = prev_next_occurrence(addrs)
+    return np.minimum(nxt, n), occ
+
+
 def test_ro_live_counts_ref_matches_numpy():
+    """The guard's host prefix sum equals the dense (i, j)-plane oracle."""
     import jax.numpy as jnp
 
-    from repro.kernels.cache_sim.ops import ro_live_counts_accel
+    from repro.core.batch_sim import _live_counts
     from repro.kernels.cache_sim.ref import live_counts_ref
-    rng = np.random.default_rng(5)
-    addrs = rng.integers(0, 30, 500).astype(np.int64)
-    occ = rng.random(500) < 0.6
-    _, nxt = prev_next_occurrence(addrs)
-    nxt = np.minimum(nxt, 500)
-    d = (np.bincount(np.flatnonzero(occ), minlength=501)
-         - np.bincount(nxt[occ], minlength=501))
-    want = np.cumsum(d[:500])
-    got = ro_live_counts_accel(nxt, occ)        # O(n) delta-cumsum path
-    assert np.array_equal(got, want)
+    nxt, occ = _live_count_case(5, 500, 30, 0.6)
+    got = _live_counts(nxt, np.flatnonzero(occ))
     dense = np.asarray(live_counts_ref(jnp.asarray(nxt, jnp.int32),
                                        jnp.asarray(occ, jnp.int32)))
-    assert np.array_equal(dense, want)          # dense (i, j)-plane oracle
+    assert np.array_equal(got, dense)
 
 
 @pytest.mark.slow
 def test_ro_live_counts_kernel_interpret():
-    from repro.kernels.cache_sim.ops import ro_live_counts_accel
-    rng = np.random.default_rng(6)
-    addrs = rng.integers(0, 25, 400).astype(np.int64)
-    occ = rng.random(400) < 0.5
-    _, nxt = prev_next_occurrence(addrs)
-    nxt = np.minimum(nxt, 400)
-    lin = ro_live_counts_accel(nxt, occ)
-    ker = ro_live_counts_accel(nxt, occ, use_kernel=True)
-    assert np.array_equal(lin, ker)
+    import jax.numpy as jnp
+
+    from repro.core.batch_sim import _live_counts
+    from repro.kernels.cache_sim.kernel import live_count_scan
+    nxt, occ = _live_count_case(6, 400, 25, 0.5)
+    ker = live_count_scan(jnp.asarray(nxt, jnp.int32),
+                          jnp.asarray(occ, jnp.int32), interpret=True)
+    assert np.array_equal(_live_counts(nxt, np.flatnonzero(occ)),
+                          np.asarray(ker))

@@ -107,11 +107,11 @@ def _tapes(w: int, n: int = 400, space: int = 120, tenants: int = 4):
     return out
 
 
-def _manager(profile=None):
+def _manager(profile=None, **kwargs):
     return ECICacheManager(capacity=72, tenant_names=[f"t{i}" for i in
                                                       range(4)],
                            c_min=6, w_threshold=0.1, initial_blocks=18,
-                           profile=profile)
+                           profile=profile, **kwargs)
 
 
 def _recorded(monkeypatch, module, name, log):
@@ -123,20 +123,47 @@ def _recorded(monkeypatch, module, name, log):
     monkeypatch.setattr(module, name, wrapped)
 
 
-@pytest.mark.parametrize("accel", [False, True], ids=["host", "device"])
-def test_run_window_stages_and_counters(monkeypatch, accel):
+def _same_run(a, b, capacity2):
+    """Two managers that ran the same windows decided and hold the same."""
+    assert len(a.history) == len(b.history)
+    for x, y in zip(a.history, b.history):
+        np.testing.assert_array_equal(x.sizes, y.sizes)
+        np.testing.assert_array_equal(x.sizes2, y.sizes2)
+        assert x.policies == y.policies and x.policies2 == y.policies2
+    assert a.summary() == b.summary()
+    for ta, tb in zip(a.tenants, b.tenants):
+        assert ta.result == tb.result
+        levels = ((ta.cache, tb.cache), (ta.cache2, tb.cache2)) \
+            if capacity2 else ((ta.cache, tb.cache),)
+        for ca, cb in levels:
+            for xa, xb in zip(ca.state_arrays(), cb.state_arrays()):
+                np.testing.assert_array_equal(xa, xb)
+
+
+def _three_windows(monkeypatch, accel, capacity2, profile=None):
     monkeypatch.setattr(batch_sim, "_ACCEL", accel)
-    tokens, launches = [], []
-    for name in ("_ro_token_replay", "_ro_token_replay_levels",
-                 "ro_token_replay_device", "ro_token_replay_levels_device"):
-        _recorded(monkeypatch, batch_sim, name, tokens)
-    for name in ("ro_live_counts_accel", "stack_distances_accel",
-                 "residency_levels_accel", "stack_distances_segments_accel"):
-        _recorded(monkeypatch, cache_sim_ops, name, launches)
-    prof = StageProfile()
-    mgr = _manager(prof)
+    mgr = _manager(profile, capacity2=capacity2)
     for w in range(3):
         mgr.run_window(_tapes(w))
+    return mgr
+
+
+@pytest.mark.parametrize("accel, capacity2", [
+    pytest.param(False, 0, id="host"),
+    pytest.param(True, 0, id="device"),
+    pytest.param(False, 48, id="host_two_level"),
+    pytest.param(True, 48, id="device_two_level")])
+def test_run_window_stages_and_counters(monkeypatch, accel, capacity2):
+    """Stages, counters and host syncs of RO tenants under eviction
+    pressure; on the accelerator path every result equals the host's."""
+    tokens, launches = [], []
+    for name in ("_ro_token_replay", "_ro_token_replay_levels"):
+        _recorded(monkeypatch, batch_sim, name, tokens)
+    for name in ("stack_distances_accel", "residency_levels_accel",
+                 "stack_distances_segments_accel"):
+        _recorded(monkeypatch, cache_sim_ops, name, launches)
+    prof = StageProfile()
+    mgr = _three_windows(monkeypatch, accel, capacity2, prof)
     assert all(t.policy.value == "ro" for t in mgr.tenants)
     assert prof.windows == 3
     assert {s: prof.parents.get(s, "missing") for s in WINDOW_STAGES} \
@@ -149,11 +176,12 @@ def test_run_window_stages_and_counters(monkeypatch, accel):
         - prof.times["partition"])
     assert tokens and prof.counts["ro_tokens"] == sum(n for _, n in tokens)
     if accel:
-        assert all(name.startswith("ro_token_replay") for name, _ in tokens)
         # the exact monitor reuses the replay's distances: no launch of
-        # its own; every replay program and token replay is one fetch
+        # its own; every replay program is one fetch
         assert "stack_distances_segments_accel" not in dict(launches)
-        assert prof.syncs == len(launches) + len(tokens)
+        assert prof.syncs == len(launches)
+        _same_run(_three_windows(monkeypatch, False, capacity2), mgr,
+                  capacity2)
     else:
         assert not launches and prof.syncs == 0
 

@@ -18,9 +18,7 @@ from oracle import (EngineDiff, RESULT_FIELDS, assert_results_equal,
 from repro.core import (ECICacheManager, Trace, WritePolicy,
                         assign_write_policy_levels, build_hit_ratio_function,
                         greedy_allocate, make_manager, reuse_distances,
-                        ro_token_replay_device, simulate, simulate_batch,
-                        two_level_solve)
-from repro.core.batch_sim import _ro_token_replay
+                        simulate, simulate_batch, two_level_solve)
 from repro.core.simulator import LRUCache, rebalance_levels
 from repro.data.traces import msr_trace
 
@@ -121,25 +119,6 @@ def test_clean_l2_flushes_at_demotion(engine_diff):
     # w(0) installs dirty; r(1) demotes 0 -> flush charged at demote
     assert r.total_latency == pytest.approx(1.0 + 20.0 + 5.0)
     assert list(diff.got2[0]._od.items()) == [(0, False)]
-
-
-# ------------------------------------------------ RO token loop, on device
-@settings(max_examples=examples(60), deadline=None)
-@given(two_level_strategy(max_n=80, max_addr=5), st.integers(1, 4))
-def test_ro_token_replay_device_matches_host(trace_list, cap):
-    t = mk_trace(trace_list)
-    if len(t) == 0:
-        return
-    from repro.core.trace import prev_next_occurrence
-    prev, nxt = prev_next_occurrence(t.addrs)
-    nxt = np.minimum(nxt, len(t))
-    force = np.zeros(len(t), bool)
-    force[::3] = True
-    d1, y1, f1 = _ro_token_replay(t.is_read, prev, nxt, force, cap)
-    d2, y2, f2 = ro_token_replay_device(t.is_read, prev, nxt, force, cap)
-    assert np.array_equal(d1, d2)
-    assert np.array_equal(y1, y2)
-    assert f1 == f2
 
 
 # ------------------------------------------------ kernel both-level masks
