@@ -1,11 +1,13 @@
 #!/usr/bin/env python3
-"""Chip benchmark of the ECI-Cache control plane: one run of one cell.
+"""Chip benchmark of ECI-Cache: one run of one cell.
 
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> \\
         --trace <0|1>
 
 A cell of ``BENCHMARK.json`` names a configuration (``configs/``) and a
-traffic mix (``traffic/``).  The run builds the tape pool from ``--seed``,
+traffic mix (``traffic/``).  A configuration of ``"kind": "serving"`` is
+run by ``serve_cell.py`` (``cell_runner``); any other is the control plane,
+run here.  The run builds the tape pool from ``--seed``,
 builds ``ECICacheManager`` from the configuration and warms it up over
 whole cycles of the pool (set-up), then runs windows back to back
 for ``--seconds`` (one window in flight, the last one finished and
@@ -28,6 +30,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
@@ -89,8 +92,15 @@ def device_info() -> dict:
 
 
 def read_metric(name: str, ctx: dict):
+    """``metrics/<name>.py``'s ``read(ctx)``.  A quantity split by the
+    end-to-end metric it moves (``<quantity>.<part>``, such as
+    ``device_idle_pct.serve``) reads with its quantity's reader where it
+    has none of its own."""
+    path = BENCH / "metrics" / f"{name}.py"
+    if not path.exists():
+        path = BENCH / "metrics" / f"{name.split('.')[0]}.py"
     spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name}", BENCH / "metrics" / f"{name}.py")
+        f"chipbench_metric_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read(ctx)
@@ -222,6 +232,16 @@ def run_cell(workload: str | C.Cell, seed: int, seconds: float,
             "metrics": metrics, "device": device, **extra, "checks": shown}
 
 
+def cell_runner(workload: str):
+    """The run of ``workload``: ``serve_cell.run_cell`` where its
+    configuration is of ``"kind": "serving"``, ``run_cell`` otherwise."""
+    import serve_cell
+    if serve_cell.is_serving(workload):
+        return functools.partial(serve_cell.run_cell,
+                                 harness=sys.modules[__name__])
+    return run_cell
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -230,8 +250,8 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     try:
-        result = run_cell(args.workload, args.seed, args.seconds,
-                          bool(args.trace))
+        result = cell_runner(args.workload)(args.workload, args.seed,
+                                       args.seconds, bool(args.trace))
     except NoDevice as e:
         print(f"chipbench: {e}; nothing was run", file=sys.stderr)
         return 1

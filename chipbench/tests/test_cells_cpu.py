@@ -1,13 +1,17 @@
-"""Every cell's loop end to end at a tiny size on the CPU: set-up,
-warm-up, measured window, the reference check, and the result line."""
+"""Every control-plane cell's loop end to end at a tiny size on the CPU:
+set-up, warm-up, measured window, the reference check, and the result
+line (the serving cells: ``test_serving_cells.py``)."""
 import json
 
 import pytest
 
 from conftest import BENCH, tiny
 
-CELLS = [w["name"] for w in json.loads(
-    (BENCH.parent / "BENCHMARK.json").read_text())["workloads"]]
+_SPEC = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+_KIND = {c["name"]: json.loads((BENCH.parent / c["file"]).read_text())
+         .get("kind") for c in _SPEC["configs"]}
+CELLS = [w["name"] for w in _SPEC["workloads"]
+         if _KIND[w["config"]] != "serving"]
 
 
 @pytest.mark.parametrize("name", CELLS)

@@ -32,6 +32,8 @@ def test_every_cell_mix_covers_its_tenants():
     cfgs = {c["name"]: json.loads((BENCH.parent / c["file"]).read_text())
             for c in spec["configs"]}
     for w in spec["workloads"]:
+        if cfgs[w["config"]].get("kind") == "serving":
+            continue        # test_serving_cells.py
         mix = generator.load_mix(w["traffic"])
         n = cfgs[w["config"]]["tenants"]["count"]
         assert len(generator.tenant_lengths(mix, n)) == n
