@@ -1,14 +1,16 @@
 """The runner of a serving cell: a configuration of ``"kind": "serving"``.
 
-The system under test is the program's serving tier as
-``repro.launch.serve`` builds it: ``MultiTenantEngine`` over a
-``TieredKVCache`` whose HBM page pool (``BlockPool``) is partitioned
-across tenants by ``ECICacheManager``.  Everything particular to a cell
-is read from its files:
+The system under test is the program's serving tier:
+``MultiTenantEngine`` over a ``TieredKVCache`` whose HBM page pool is
+partitioned across tenants by ``ECICacheManager``.  The program builds
+it, and decides the pool's layout, through ``build_server``
+(``serve_build``); the harness only drives and judges it.  Everything
+particular to a cell is read from its files:
 
   * the configuration (``configs/<name>.json``): ``arch``, resolved with
     ``repro.configs.get_config`` (its widths must equal the file's
-    ``model`` keys, the published ``config.json``'s names), ``tenants``,
+    ``model`` keys, the published ``config.json``'s names: ``WIDTHS``,
+    and the file's own ``widths`` for fields ``WIDTHS`` lacks), ``tenants``,
     ``pool`` (pages, page size, pages a sequence may hold, dtype),
     ``tiered`` (events per ECI window), ``manager`` (the manager's
     keywords) and ``reference``, the plain reference under ``serve_ref/``,
@@ -29,6 +31,15 @@ time to first token runs from its due time, so a late generator counts
 against the server.  Pool and ECI state carry over from set-up.
 Afterwards the reference judges what the timed path produced
 (``serve_check``).
+
+The hooks (``hook``) hold the program to this contract, and raise when
+it is set up if it is broken: ``repro.serve.engine`` calls its prefill
+as ``prefill_with_kv`` and its decode step as ``_decode_step_jit``
+(``serve_tracing.DECODE_PROGRAM``, the program a traced run counts once
+a step), through those module names; the decode step has the parameters
+``tables`` (the batch's block tables) and ``lens``; both return the
+logits first.  Everything else they take, the cache among it, is passed
+through as it comes.
 """
 from __future__ import annotations
 
@@ -51,6 +62,7 @@ import serving as traffic  # noqa: E402  (the serving traffic generator)
 
 JUDGED = 4                # requests the reference judges per run
 MAX_WINDOW_S = 240.0      # a window that has not drained by then fails
+PREFILL_PROGRAM = "prefill_with_kv"   # the engine's prefill, hooked by name
 
 
 def spec() -> dict:
@@ -99,24 +111,44 @@ class ServeCell:
         return self.config["model"]
 
 
+# published config.json key -> the program's ModelConfig field
 WIDTHS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
           "num_attention_heads": "n_heads",
           "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
           "intermediate_size": "d_ff", "vocab_size": "vocab_size",
           "rope_theta": "rope_theta", "rms_norm_eps": "rms_eps",
-          "tie_word_embeddings": "tie_embeddings"}
+          "tie_word_embeddings": "tie_embeddings",
+          "n_routed_experts": "n_experts", "num_experts": "n_experts",
+          "num_experts_per_tok": "top_k",
+          "moe_intermediate_size": "expert_d_ff",
+          "kv_lora_rank": "kv_lora_rank", "q_lora_rank": "q_lora_rank",
+          "qk_nope_head_dim": "qk_nope_dim",
+          "qk_rope_head_dim": "qk_rope_dim", "v_head_dim": "v_head_dim"}
 
 
 def program_config(cell: ServeCell):
     """The program's ``ModelConfig`` for the cell's ``arch`` (a
     configuration may name ``smoke_arch`` instead: the architecture's
-    reduced preset, for tests), checked against the file's widths."""
+    reduced preset, for tests), checked against the file's widths: each
+    ``model`` key of ``WIDTHS`` or of the file's ``widths`` (published
+    key -> field, for fields ``WIDTHS`` lacks) has to equal the field, a
+    null read as 0 (``q_lora_rank`` null: no query compression).  A
+    mapped field the program lacks raises."""
     from repro.configs import get_config, get_smoke_config
     c = cell.config
     cfg = (get_smoke_config(c["smoke_arch"]) if "smoke_arch" in c
            else get_config(c["arch"]))
-    off = {k: (v, getattr(cfg, WIDTHS[k])) for k, v in cell.model.items()
-           if k in WIDTHS and getattr(cfg, WIDTHS[k]) != v}
+    widths = {**WIDTHS, **c.get("widths", {})}
+    off = {}
+    for k, v in cell.model.items():
+        if k not in widths:
+            continue
+        if not hasattr(cfg, widths[k]):
+            raise ValueError(f"{cell.name}: the program's ModelConfig has "
+                             f"no field {widths[k]!r} for {k!r}")
+        have = getattr(cfg, widths[k])
+        if have != (0 if v is None else v):
+            off[k] = (v, have)
     if off:
         raise ValueError(f"{cell.name}: the program's {cfg.name} differs "
                          f"from the configuration: {off}")
@@ -127,38 +159,28 @@ def program_config(cell: ServeCell):
 
 
 class Server:
-    """The system under test, built as ``repro.launch.serve`` builds it,
-    with the weights made from ``seed`` by the cell's reference module."""
+    """The system under test, built by the program's builder
+    (``serve_build.builder``) from the configuration's sizes, with the
+    weights made from ``seed`` by the cell's reference module."""
 
     def __init__(self, cell: ServeCell, seed: int):
         import jax
-        import jax.numpy as jnp
-        from repro.cache import BlockPool, TieredKVCache
-        from repro.core import ECICacheManager
+        import serve_build
         from repro.models import model as M
-        from repro.models.attention import build_heads
-        from repro.serve.engine import MultiTenantEngine
         c = cell.config
         self.cfg = cfg = program_config(cell)
         self.shapes = jax.eval_shape(partial(M.init_params, cfg),
                                      jax.random.PRNGKey(0))
         params = cell.ref.weights(seed, cell.model, self.shapes)
         jax.block_until_ready(params)
-        _, hkv = build_heads(cfg, 1)
         p = c["pool"]
-        self.pool = BlockPool(p["pages"], p["page_size"], cfg.n_layers, hkv,
-                              cfg.head_dim,
-                              dtype=jnp.dtype(p["dtype"]))
-        self.manager = ECICacheManager(
-            tenant_names=[f"tenant{i}" for i in range(c["tenants"])],
-            **c["manager"])
-        self.tiered = TieredKVCache(self.pool, self.manager,
-                                    window_events=c["tiered"]
-                                    ["window_events"])
-        self.engine = MultiTenantEngine(cfg, params, self.tiered,
-                                        page_size=p["page_size"],
-                                        max_pages_per_seq=p[
-                                            "max_pages_per_seq"])
+        built = serve_build.builder()(
+            cfg, params, tenants=c["tenants"], pool_pages=p["pages"],
+            page_size=p["page_size"],
+            max_pages_per_seq=p["max_pages_per_seq"], pool_dtype=p["dtype"],
+            window_events=c["tiered"]["window_events"], manager=c["manager"])
+        self.pool, self.manager = built.pool, built.manager
+        self.tiered, self.engine = built.tiered, built.engine
         self.page = p["page_size"]
 
 
@@ -191,11 +213,25 @@ def hook(server: Server, log: Log):
     the logit rows of judged requests, and every ECI window's page events
     and decision.  Returns the function that undoes the module-level
     hooks (the rest live on the run's own objects)."""
+    import inspect
+
     import jax.numpy as jnp
     import repro.serve.engine as E
+    import serve_tracing as ST
+    for name in (PREFILL_PROGRAM, ST.DECODE_PROGRAM):
+        if not callable(getattr(E, name, None)):
+            raise AttributeError(f"repro.serve.engine has no {name}: the "
+                                 "serving hooks cannot reach the program")
+    orig_prefill_fn = getattr(E, PREFILL_PROGRAM)
+    orig_decode_fn = getattr(E, ST.DECODE_PROGRAM)
+    decode_sig = inspect.signature(orig_decode_fn)
+    lacks = {"tables", "lens"} - set(decode_sig.parameters)
+    if lacks:
+        raise TypeError(f"repro.serve.engine.{ST.DECODE_PROGRAM} has no "
+                        f"parameter {', '.join(sorted(lacks))}: "
+                        f"{decode_sig}")
     eng, mgr, tiered = server.engine, server.manager, server.tiered
     pick = _pick()
-    orig_prefill_fn, orig_decode_fn = E.prefill_with_kv, E._decode_step_jit
 
     def keep(rid, logits, i):
         """A judged request's logit row, kept on the device; set-up
@@ -205,17 +241,19 @@ def hook(server: Server, log: Log):
             if not log.warming:
                 log.kept.setdefault(rid, []).append(row)
 
-    def prefill_with_kv(*a, **k):
-        out = orig_prefill_fn(*a, **k)
+    def prefill_with_kv(*args, **kwargs):
+        out = orig_prefill_fn(*args, **kwargs)
         if log.current is not None:
             keep(log.current.rid, out[0], 0)
         return out
 
-    def decode_step(params, k_pool, v_pool, toks, tables, lens, *rest):
+    def decode_step(*args, **kwargs):
         if log.tables is not None:
-            tables = log.tables(server, log, tables)
-        out = orig_decode_fn(params, k_pool, v_pool, toks, tables, lens,
-                             *rest)
+            call = decode_sig.bind(*args, **kwargs)
+            call.arguments["tables"] = log.tables(
+                server, log, call.arguments["tables"])
+            args, kwargs = call.args, call.kwargs
+        out = orig_decode_fn(*args, **kwargs)
         for i, r in enumerate(log.batch):
             keep(r.rid, out[0], i)
         return out
@@ -291,10 +329,12 @@ def hook(server: Server, log: Log):
                                      for i in range(len(mgr.tenants))]
 
     mgr.record, mgr.analyze, tiered.rebalance = recorded, analyzed, rebalanced
-    E.prefill_with_kv, E._decode_step_jit = prefill_with_kv, decode_step
+    setattr(E, PREFILL_PROGRAM, prefill_with_kv)
+    setattr(E, ST.DECODE_PROGRAM, decode_step)
 
     def unhook():
-        E.prefill_with_kv, E._decode_step_jit = orig_prefill_fn, orig_decode_fn
+        setattr(E, PREFILL_PROGRAM, orig_prefill_fn)
+        setattr(E, ST.DECODE_PROGRAM, orig_decode_fn)
     return unhook
 
 

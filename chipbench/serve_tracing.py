@@ -9,7 +9,8 @@ Alg. 3, quota enforcement) and ``BlockPool.write_page`` (one prefill page
 scattered into the pool).  Each span is a host clock plus a
 ``jax.profiler.TraceAnnotation``; a span's self time leaves out the spans
 nested in it.  ``write_page`` returns before the device has written the
-page, so its span waits for the pool before and after the call: in the
+page, so its span waits for the pool (every device array it holds, in
+whatever layout the program gives it) before and after the call: in the
 traced run its clock is the write's device time (the untraced run does
 not wait).  A wrapped name that is gone raises: the traced run fails
 rather than report a layer as 0.
@@ -79,7 +80,9 @@ class Spans:
         pool = objs["pool"]
 
         def wait_pool():
-            jax.block_until_ready((pool.k_pages, pool.v_pages))
+            """Every device array the pool holds, whatever its layout."""
+            jax.block_until_ready([v for v in vars(pool).values()
+                                   if isinstance(v, jax.Array)])
         for name, owner in SPANNED:
             o = objs[owner]
             setattr(o, name, self.wrap(name, getattr(o, name),

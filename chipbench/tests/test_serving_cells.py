@@ -269,6 +269,224 @@ def test_shared_prefix_pages_are_freed_under_requests_in_flight(
     assert audit.first_bad
 
 
+# ------------------------------------------------- the program's system
+def smoke_cell(tmp_path, monkeypatch, model=None, widths=None):
+    """The throwaway cell, with ``model`` keys and a ``widths`` map added
+    to its configuration file."""
+    import serve_cell as S
+    name, bench, mix = smoke_bench(tmp_path)
+    path = tmp_path / "smoke.json"
+    cfg = json.loads(path.read_text())
+    cfg["model"].update(model or {})
+    if widths is not None:
+        cfg["widths"] = widths
+    path.write_text(json.dumps(cfg))
+    monkeypatch.setattr(S, "spec", lambda: bench)
+    cell = S.ServeCell(name, bench)
+    cell.mix = mix
+    return cell
+
+
+@pytest.mark.parametrize("where", ["program", "stand_in"])
+def test_the_server_is_what_the_builder_returned(tmp_path, monkeypatch,
+                                                 where):
+    """``Server`` takes its pool, manager, tiered cache and engine from
+    the program's ``build_server`` where the program has one, else from
+    the stand-in, and builds none of them itself."""
+    import repro.launch.serve as L
+    import serve_build
+    import serve_cell as S
+    built = []
+
+    def spy(*args, **kwargs):
+        built.append(real(*args, **kwargs))
+        return built[-1]
+    real = serve_build.stand_in
+    if where == "program":
+        monkeypatch.setattr(L, "build_server", spy, raising=False)
+    else:
+        monkeypatch.delattr(L, "build_server", raising=False)
+        monkeypatch.setattr(serve_build, "stand_in", spy)
+    server = S.Server(smoke_cell(tmp_path, monkeypatch), SEED)
+    assert len(built) == 1
+    b = built[0]
+    assert (server.pool, server.manager, server.tiered, server.engine) \
+        == (b.pool, b.manager, b.tiered, b.engine)
+    assert server.engine.tiered is b.tiered and b.tiered.pool is b.pool
+
+
+def test_stand_in_builds_as_the_launcher_does():
+    """The stand-in at ``launch/serve.py``'s numbers: the K/V pool of
+    shape (layers, pages, page, kv heads, head_dim), and the same tokens
+    as the system that launcher builds by hand."""
+    import jax
+    import jax.numpy as jnp
+    import serve_build
+    from repro.cache import BlockPool, TieredKVCache
+    from repro.configs import get_smoke_config
+    from repro.core import ECICacheManager
+    from repro.models import model as M
+    from repro.serve.engine import MultiTenantEngine, Request
+    cfg = get_smoke_config("qwen3_0_6b")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    tenants, pages, page, capacity = 2, 64, 8, 24
+    mgr = {"capacity": capacity, "c_min": 8,
+           "initial_blocks": capacity // tenants}
+    built = serve_build.stand_in(
+        cfg, params, tenants=tenants, pool_pages=pages, page_size=page,
+        max_pages_per_seq=32, pool_dtype=cfg.dtype, window_events=128,
+        manager=mgr)
+    assert built.pool.k_pages.shape == built.pool.v_pages.shape \
+        == (cfg.n_layers, pages, page, cfg.n_kv_heads, cfg.head_dim)
+    assert built.pool.k_pages.dtype == jnp.dtype(cfg.dtype)
+    pool = BlockPool(pages, page, cfg.n_layers, cfg.n_kv_heads, cfg.head_dim,
+                     dtype=jnp.dtype(cfg.dtype))
+    tiered = TieredKVCache(pool, ECICacheManager(
+        capacity, [f"tenant{i}" for i in range(tenants)], c_min=8,
+        initial_blocks=capacity // tenants), window_events=128)
+    by_hand = MultiTenantEngine(cfg, params, tiered, page_size=page,
+                                max_pages_per_seq=32)
+
+    def served(engine):
+        rng = np.random.default_rng(0)
+        for i in range(4):
+            engine.submit(Request(tenant=i % tenants, prompt=rng.integers(
+                0, cfg.vocab_size, 12 + 5 * i).astype(np.int32),
+                max_new_tokens=4))
+        engine.run(max_steps=40)
+        return sorted((r.rid, r.generated) for r in engine.completed)
+    got = served(built.engine)
+    assert len(got) == 4 and got == served(by_hand)
+
+
+def test_stand_in_refuses_latent_attention():
+    import jax
+    import serve_build
+    from repro.configs import get_smoke_config
+    from repro.models import model as M
+    cfg = get_smoke_config("minicpm3_4b")
+    shapes = jax.eval_shape(lambda: M.init_params(cfg,
+                                                  jax.random.PRNGKey(0)))
+    with pytest.raises(ValueError, match="latent attention"):
+        serve_build.stand_in(cfg, shapes, tenants=2, pool_pages=8,
+                             page_size=8, max_pages_per_seq=4,
+                             pool_dtype="bfloat16", window_events=16,
+                             manager={"capacity": 8})
+
+
+def hooks_only():
+    """A server of stand-ins that holds just what ``hook`` wraps."""
+    from types import SimpleNamespace as NS
+    return NS(engine=NS(_prefill_one=None, _decode_batch=None, active=[]),
+              manager=NS(record=None, analyze=None, tenants=[]),
+              tiered=NS(rebalance=None, quotas={}), pool=NS())
+
+
+@pytest.mark.parametrize("call", ["positional", "keywords"])
+def test_hook_swaps_tables_by_name(monkeypatch, call):
+    """A decode step of another cache layout (one latent pool, tables in
+    fourth place): the block-table hook, which ``stale_page`` uses,
+    replaces ``tables`` by its name, however the step is called."""
+    import repro.serve.engine as E
+    import serve_cell as S
+    seen = {}
+
+    def latent_step(params, kv_pool, toks, tables, lens, cfg, tp):
+        seen.update(pool=kv_pool, tables=tables, lens=lens)
+        return ("logits",)
+    monkeypatch.setattr(E, "_decode_step_jit", latent_step)
+    log = S.Log()
+    log.tables = lambda server, log, t: t + 100
+    unhook = S.hook(hooks_only(), log)
+    try:
+        if call == "positional":
+            out = E._decode_step_jit("p", "pool", "toks", np.arange(2), 3,
+                                     None, 1)
+        else:
+            out = E._decode_step_jit("p", "pool", "toks", lens=3, cfg=None,
+                                     tp=1, tables=np.arange(2))
+    finally:
+        unhook()
+    assert out == ("logits",) and E._decode_step_jit is latent_step
+    assert seen["tables"].tolist() == [100, 101]
+    assert seen["lens"] == 3 and seen["pool"] == "pool"
+
+
+def step_without_tables(params, pool, toks, block_tables, lens, cfg, tp):
+    return None
+
+
+def step_without_lens(params, pool, toks, tables, lengths, cfg, tp):
+    return None
+
+
+@pytest.mark.parametrize("broken", ["no_tables", "no_lens", "no_prefill"])
+def test_a_broken_hook_contract_fails_at_hook_time(monkeypatch, broken):
+    import repro.serve.engine as E
+    import serve_cell as S
+    if broken == "no_prefill":
+        monkeypatch.delattr(E, "prefill_with_kv")
+        err = AttributeError
+    else:
+        monkeypatch.setattr(E, "_decode_step_jit", {
+            "no_tables": step_without_tables,
+            "no_lens": step_without_lens}[broken])
+        err = TypeError
+    with pytest.raises(err):
+        S.hook(hooks_only(), S.Log())
+
+
+def test_write_page_span_waits_on_every_pool_array(monkeypatch):
+    """A pool of another layout: the ``write_page`` span waits on each of
+    its device arrays, before and after the write, whatever their names."""
+    import jax
+    import jax.numpy as jnp
+    import serve_tracing as ST
+    from types import SimpleNamespace as NS
+
+    class Pool:
+        def __init__(self):
+            self.latent = jnp.zeros(4)
+            self.rope = jnp.zeros(2)
+            self.scales = jnp.ones(3)
+            self.meta = {}
+
+        def write_page(self, *args):
+            return "written"
+    pool = Pool()
+    waited = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waited.append(x) or x)
+    ST.Spans().install({"engine": NS(_prefill_one=None, _decode_batch=None),
+                        "tiered": NS(rebalance=None), "pool": pool})
+    assert pool.write_page() == "written"
+    assert len(waited) == 2
+    for w in waited:
+        assert sorted(map(id, w)) == sorted(
+            map(id, (pool.latent, pool.rope, pool.scales)))
+
+
+@pytest.mark.parametrize("model,widths,err", [
+    ({"moe_intermediate_size": 1408}, None, "differs"),
+    ({"kv_lora_rank": 512, "qk_rope_head_dim": 64}, None, "differs"),
+    ({"hidden_width": 96}, {"hidden_width": "d_model"}, "differs"),
+    ({"hidden_width": 64}, {"hidden_width": "no_such_field"}, "no field"),
+    ({"q_lora_rank": None, "kv_lora_rank": 0, "hidden_width": 64},
+     {"hidden_width": "d_model"}, None),
+])
+def test_program_widths_are_checked(tmp_path, monkeypatch, model, widths,
+                                    err):
+    """Expert and latent widths, and a configuration's own ``widths``,
+    have to equal the program's fields (a null reads as 0)."""
+    import serve_cell as S
+    cell = smoke_cell(tmp_path, monkeypatch, model, widths)
+    if err is None:
+        assert S.program_config(cell).d_model == 64
+    else:
+        with pytest.raises(ValueError, match=err):
+            S.program_config(cell)
+
+
 # --------------------------------------------------------------- dispatch
 def test_a_new_serving_configuration_needs_only_files(smoke, tmp_path,
                                                       monkeypatch):
