@@ -3,7 +3,9 @@
 The pool is a set of fixed-size pages living in device arrays
 (``[n_pages, page_size, kv_heads, head_dim]`` per layer per k/v); page
 *contents* stay on device and are only touched by JAX ops (scatter of fresh
-KV, gather via block tables inside the paged-attention kernel).  Page
+KV, gather via block tables inside the paged-attention kernel).  Every
+update donates the pool's arrays to one jitted program, so XLA writes the
+existing buffers in place instead of copying the whole pool.  Page
 *metadata* — free list, per-tenant LRU ordering, content keys for prefix
 reuse — is host-side, exactly like vLLM's block manager.
 
@@ -15,12 +17,27 @@ from __future__ import annotations
 
 import dataclasses
 from collections import OrderedDict
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["PageMeta", "BlockPool"]
+
+
+@partial(jax.jit, donate_argnums=0)
+def _set_page(pages, new, pid):
+    """Each array of ``pages`` with page ``pid`` of every layer set to its
+    entry of ``new``; ``pages`` is donated, so the write is in place."""
+    return tuple(p.at[:, pid].set(n) for p, n in zip(pages, new))
+
+
+@partial(jax.jit, donate_argnums=0)
+def _set_tokens(pages, new, pids, offs):
+    """Each array of ``pages`` with request b's token ``new[:, b]`` set at
+    (every layer, ``pids[b]``, ``offs[b]``); donated, so in place."""
+    return tuple(p.at[:, pids, offs].set(n) for p, n in zip(pages, new))
 
 
 @dataclasses.dataclass
@@ -55,8 +72,10 @@ class BlockPool:
         # per-tenant LRU of resident pages: tenant -> OrderedDict[page_id]
         self.lru: dict[int, OrderedDict[int, None]] = {}
         self.by_key: dict[tuple, int] = {}
+        # pool_copies: device updates whose donation did not take, so the
+        # update copied the pool (0 on every backend that honours donation)
         self.stats = {"admitted": 0, "evicted": 0, "reused": 0,
-                      "writes": 0}
+                      "writes": 0, "pool_copies": 0}
         # demote-on-evict hook: called as on_evict(pid, meta) after a page
         # leaves the pool (TieredKVCache pushes the victim into the managed
         # host tier — the L2 of the serving hierarchy)
@@ -167,7 +186,23 @@ class BlockPool:
 
         layer_slice_*: [n_layers, page_size, kv_heads, head_dim].
         """
+        self._update(_set_page, (layer_slice_k, layer_slice_v),
+                     np.int32(pid))
+
+    def write_tokens(self, k_new: jax.Array, v_new: jax.Array,
+                     pids: np.ndarray, offs: np.ndarray) -> None:
+        """Write one decode step's tokens: request b's KV at (every layer,
+        page ``pids[b]``, offset ``offs[b]``).
+
+        k_new/v_new: [n_layers, B, kv_heads, head_dim].
+        """
+        self._update(_set_tokens, (k_new, v_new),
+                     np.asarray(pids, np.int32), np.asarray(offs, np.int32))
+
+    def _update(self, fn, new, *where) -> None:
         if self.k_pages is None:
             return
-        self.k_pages = self.k_pages.at[:, pid].set(layer_slice_k)
-        self.v_pages = self.v_pages.at[:, pid].set(layer_slice_v)
+        old = (self.k_pages, self.v_pages)
+        self.k_pages, self.v_pages = fn(old, new, *where)
+        if not all(a.is_deleted() for a in old):
+            self.stats["pool_copies"] += 1

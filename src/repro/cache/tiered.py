@@ -352,6 +352,7 @@ class TieredKVCache:
             "host_resident": sum(len(q) for q in self.host_lru.values()),
             "resident_pages": sum(self.pool.resident(i)
                                   for i in range(len(self.stats))),
+            "pool_copies": self.pool.stats["pool_copies"],
             "quotas": dict(self.quotas),
             "host_quotas": dict(self.host_quotas),
             "policies": {i: p.value for i, p in self.policies.items()},

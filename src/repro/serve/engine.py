@@ -132,7 +132,7 @@ class MultiTenantEngine:
             else:
                 self.tiered.access_page(req.tenant, key, fresh=True)
                 pid = self.pool.by_key.get(key)
-                if pid is not None and self.pool.k_pages is not None:
+                if pid is not None:
                     sl = slice(pi * page, (pi + 1) * page)
                     self.pool.write_page(ks[:, 0, sl], vs[:, 0, sl], pid)
             if pid is None:
@@ -141,7 +141,7 @@ class MultiTenantEngine:
                 # can still attend — latency accounting treats it as a
                 # host-tier fetch, and it never counts against quotas.
                 pid, _ = self.pool.allocate(-2, None, quota=None)
-                if pid is not None and self.pool.k_pages is not None:
+                if pid is not None:
                     sl = slice(pi * page, (pi + 1) * page)
                     self.pool.write_page(ks[:, 0, sl], vs[:, 0, sl], pid)
             if pid is None:
@@ -187,20 +187,10 @@ class MultiTenantEngine:
             self.params, self.pool.k_pages, self.pool.v_pages,
             jnp.asarray(toks), jnp.asarray(tables), jnp.asarray(lens),
             self.cfg, self.tp)
-        # scatter the new token's KV into each request's current page
-        if self.pool.k_pages is not None:
-            pids = np.array([r.pages[r.length // page] for r in reqs])
-            offs = np.array([r.length % page for r in reqs])
-            L = self.pool.shape[0]
-            li = np.repeat(np.arange(L), B)
-            pi = np.tile(pids, L)
-            oi = np.tile(offs, L)
-            kn = k_new.transpose(0, 1, 2, 3)      # [L,B,Hkv,D]
-            self.pool.k_pages = self.pool.k_pages.at[li, pi, oi].set(
-                kn.reshape(L * B, *kn.shape[2:]))
-            vn = v_new.transpose(0, 1, 2, 3)
-            self.pool.v_pages = self.pool.v_pages.at[li, pi, oi].set(
-                vn.reshape(L * B, *vn.shape[2:]))
+        # write the new token's KV into each request's current page
+        self.pool.write_tokens(
+            k_new, v_new, [r.pages[r.length // page] for r in reqs],
+            [r.length % page for r in reqs])
         nxt = np.asarray(jnp.argmax(logits[:, :cfg.vocab_size], axis=-1))
         for i, r in enumerate(reqs):
             r.length += 1

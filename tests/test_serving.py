@@ -15,12 +15,12 @@ KEY = jax.random.PRNGKey(0)
 
 
 def _engine(n_pages=256, window_events=10**9, capacity=128, page=8,
-            tenants=("t0", "t1")):
+            tenants=("t0", "t1"), dtype=jnp.float32):
     cfg = get_smoke_config("qwen3_0_6b")
     params = M.init_params(cfg, KEY, tp=1)
     hq, hkv = build_heads(cfg, 1)
     pool = BlockPool(n_pages, page, cfg.n_layers, hkv, cfg.head_dim,
-                     dtype=jnp.float32)
+                     dtype=dtype)
     mgr = ECICacheManager(capacity, list(tenants), c_min=8,
                           initial_blocks=32)
     tiered = TieredKVCache(pool, mgr, window_events=window_events)
@@ -58,6 +58,88 @@ def test_paged_decode_matches_dense_decode():
             params, cfg, jnp.asarray([dense_tokens[-1]], jnp.int32), cache)
         dense_tokens.append(int(jnp.argmax(out[0, :cfg.vocab_size])))
     assert paged_tokens == dense_tokens
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_in_place_pool_writes_are_the_eager_writes(dtype):
+    """The donated page and token writes leave the pool bit-equal to the
+    eager ``.at[].set`` updates they replace, and copy nothing."""
+    eng, pool, tiered, cfg, _ = _engine(dtype=dtype)
+    shadow = [jnp.zeros(pool.shape, dtype), jnp.zeros(pool.shape, dtype)]
+    calls = {"pages": 0, "tokens": 0}
+    write_page, write_tokens = pool.write_page, pool.write_tokens
+
+    def page(k, v, pid):
+        calls["pages"] += 1
+        shadow[0] = shadow[0].at[:, pid].set(k)
+        shadow[1] = shadow[1].at[:, pid].set(v)
+        write_page(k, v, pid)
+
+    def tokens(k_new, v_new, pids, offs):
+        calls["tokens"] += 1
+        # the engine's old eager scatter, from the requests themselves
+        reqs = [r for r in eng.active if not r.done]
+        pids_ = np.array([r.pages[r.length // eng.page] for r in reqs])
+        offs_ = np.array([r.length % eng.page for r in reqs])
+        L, B = k_new.shape[:2]
+        li = np.repeat(np.arange(L), B)
+        pi, oi = np.tile(pids_, L), np.tile(offs_, L)
+        shadow[0] = shadow[0].at[li, pi, oi].set(
+            k_new.reshape(L * B, *k_new.shape[2:]))
+        shadow[1] = shadow[1].at[li, pi, oi].set(
+            v_new.reshape(L * B, *v_new.shape[2:]))
+        write_tokens(k_new, v_new, pids, offs)
+
+    pool.write_page, pool.write_tokens = page, tokens
+    rng = np.random.default_rng(4)
+    for t, n in ((0, 13), (1, 20)):          # both cross a page in decode
+        eng.submit(Request(tenant=t, prompt=rng.integers(
+            0, cfg.vocab_size, n).astype(np.int32), max_new_tokens=6))
+    eng.run(16)
+    assert len(eng.completed) == 2
+    assert calls == {"pages": 5, "tokens": 5}
+    assert pool.stats["pool_copies"] == 0
+    assert tiered.summary()["pool_copies"] == 0
+    for got, want in zip((pool.k_pages, pool.v_pages), shadow):
+        got, want = np.asarray(got), np.asarray(want)
+        assert np.count_nonzero(got.any(axis=(0, 3, 4))) == 13 + 5 + 20 + 5
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("write", ["page", "tokens"])
+def test_donated_pool_write_leaves_no_stale_handle(write):
+    pool = BlockPool(8, 4, 2, 2, 16, dtype=jnp.bfloat16)
+    old = (pool.k_pages, pool.v_pages)
+    if write == "page":
+        new = jnp.ones((2, 4, 2, 16), jnp.bfloat16)
+        pool.write_page(new, new, 3)
+    else:
+        new = jnp.ones((2, 3, 2, 16), jnp.bfloat16)
+        pool.write_tokens(new, new, [1, 5, 6], [0, 3, 2])
+    assert all(a.is_deleted() for a in old)
+    for a in (pool.k_pages, pool.v_pages):
+        assert not a.is_deleted()
+        assert a.shape == pool.shape and a.dtype == jnp.bfloat16
+        assert int(jnp.count_nonzero(a)) == new.size
+    assert pool.stats["pool_copies"] == 0
+
+    meta_only = BlockPool(8, 4, 2, 2, 16, allocate_device=False)
+    meta_only.write_page(new, new, 3)
+    meta_only.write_tokens(new, new, [1, 5, 6], [0, 3, 2])
+    assert meta_only.k_pages is None and meta_only.v_pages is None
+    assert meta_only.stats["pool_copies"] == 0
+
+
+def test_pool_copies_counts_an_update_that_copied():
+    """An update whose old buffers outlive it (no donation) is counted."""
+    pool = BlockPool(8, 4, 2, 2, 16, dtype=jnp.float32)
+    new = jnp.ones((2, 4, 2, 16), jnp.float32)
+    copy = jax.jit(lambda pages, new, pid: tuple(
+        p.at[:, pid].set(n) for p, n in zip(pages, new)))
+    pool._update(copy, (new, new), np.int32(2))
+    assert pool.stats["pool_copies"] == 1
+    pool.write_page(new, new, 2)
+    assert pool.stats["pool_copies"] == 1
 
 
 def test_ro_policy_bypasses_admissions():
